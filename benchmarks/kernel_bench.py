@@ -1,14 +1,16 @@
-"""Pallas gf_matmul kernel micro-bench (interpret mode on CPU — the numbers
-are correctness-path timings, the TPU perf model lives in the roofline)."""
+"""Pallas gf_matmul / NTT kernel micro-bench.  On the CPU the kernels run
+interpreted, so the numbers there are correctness-path timings, not a
+measure of the chip (`interp_mode=1` marks them)."""
 from __future__ import annotations
 
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.field import FERMAT_Q
-from repro.kernels.gf_matmul import gf_matmul
+from repro.kernels.ops import gf_matmul, ntt
 from repro.kernels.ref import gf_matmul_ref
 
 
@@ -22,6 +24,7 @@ def _time(fn, reps=3):
 
 def rows() -> list[str]:
     rng = np.random.default_rng(3)
+    interp = int(jax.default_backend() == "cpu")
     out = []
     for (M, K, N) in [(128, 128, 128), (256, 256, 128)]:
         a = jnp.asarray(rng.integers(0, FERMAT_Q, (M, K)).astype(np.uint32))
@@ -30,9 +33,7 @@ def rows() -> list[str]:
         us_r = _time(lambda: gf_matmul_ref(a, b))
         gf_ops = 2 * M * K * N
         out.append(f"kernel/gf_matmul_{M}x{K}x{N},{us_k:.0f},"
-                   f"gf_ops={gf_ops};interp_mode=1;ref_us={us_r:.0f}")
-
-    from repro.kernels.ntt import ntt
+                   f"gf_ops={gf_ops};interp_mode={interp};ref_us={us_r:.0f}")
 
     for K in (256, 1024):
         W = 128

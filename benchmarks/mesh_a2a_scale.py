@@ -17,13 +17,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.field import FERMAT
 from repro.core.matrices import permuted_dft_matrix
 from repro.core.shardmap_exec import (
-    build_dft_tables, build_universal_tables, mesh_dft, mesh_universal_a2a,
-    shard_map)
+    build_dft_tables, build_universal_tables, mesh_dft, mesh_universal_a2a)
 from repro.launch.hlo_cost import analyze
 
 

@@ -34,7 +34,7 @@ Sections:
   table1/*       — Table I: universal / DFT / Vandermonde A2A costs vs theory
   multireduce/*  — Sec. II comparison vs Jeong et al. [21] + strawman
   framework/*    — Thm. 1/2/7/9 end-to-end decentralized encoding costs
-  kernel/*       — Pallas gf_matmul micro-bench (interpret mode)
+  kernel/*       — Pallas gf_matmul / NTT micro-bench (interpreted on CPU)
   recover/*      — decode vs encode: DecodePlan kernel hot path + closed-form
                    network costs (the repair half of the pipeline)
   rebuild/*      — rebuild cost vs erasure count |E|: CodedSystem.rebuild
@@ -51,8 +51,9 @@ Sections:
   topo/*         — hierarchical topology: per-placement inter-tier traffic,
                    affinity-vs-flat crossover ratio, tier-model exactness
                    (benchmarks/topo_bench.py; deterministic, tightly gated)
-  mesh_encode/*  — lowered-HLO collective bytes, universal vs RS (subprocess)
-  mesh_a2a/*     — mesh A2A scaling (subprocess)
+  mesh_encode/*  — lowered-HLO collective bytes, universal vs RS (CPU-only
+                   subprocess)
+  mesh_a2a/*     — mesh A2A scaling (CPU-only subprocess)
   mesh/*         — the stable (HLO-census, no wall clock) rows of BOTH mesh
                    subprocess benches, folded into one gated section; the
                    section name "mesh" runs both scripts
@@ -189,6 +190,9 @@ def main() -> None:
                          "without their own (default 0.25)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (coded_train_bench, framework_costs, kernel_bench,
                             multireduce_compare, rebuild_bench, recover_bench,
                             serve_bench, stream_bench, table1_costs,
@@ -230,9 +234,11 @@ def main() -> None:
             for row in mod.rows():
                 _emit(row, acc)
 
-    # mesh benches need their own process (8 forced host devices)
+    # mesh benches need their own process (forced host devices): they are
+    # CPU-only HLO censuses, and this process may already hold the chip
     env = dict(os.environ)
     env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     for name, (script, prefixes) in subproc.items():
         if not (on(name) or on("mesh")):

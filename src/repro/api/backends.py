@@ -122,15 +122,26 @@ def _mesh_axes(plan, devs):
     return Mesh(np.array(devs), ("enc",)), "enc", P("enc")
 
 
+def mesh_sharding(plan):
+    """The layout the mesh callable consumes: row k (source k) on device
+    k, so each source is placed on its own device straight from the host
+    instead of staging the whole payload on device 0."""
+    from jax.sharding import NamedSharding
+
+    mesh, _, pspec = _mesh_axes(plan, _require_devices(plan.spec.K))
+    return NamedSharding(mesh, pspec)
+
+
 def build_mesh_callable(plan):
     """Jitted global-array function (K, W) uint32 -> (K, W) uint32 running
     the plan's schedule under shard_map on the first K devices.  Device k
     holds source k; after the call devices 0..R-1 hold the sink values."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
 
     from ..core.parity import mesh_parity_encode
-    from ..core.shardmap_exec import mesh_dft, shard_map
+    from ..core.shardmap_exec import mesh_dft
 
     spec = plan.spec
     devs = _require_devices(spec.K)
@@ -191,11 +202,12 @@ def build_mesh_callable(plan):
 
 
 def run_mesh(plan, x: np.ndarray) -> np.ndarray:
-    import jax.numpy as jnp
+    import jax
 
     spec = plan.spec
     fn = plan.mesh_callable()
-    xd = jnp.asarray(np.asarray(x) % plan.field.q, jnp.uint32)
+    xd = jax.device_put((np.asarray(x) % plan.field.q).astype(np.uint32),
+                        mesh_sharding(plan))
     with kernel_span("mesh_encode", kind=spec.kind, K=spec.K,
                      w=int(xd.shape[1])):
         y = np.asarray(fn(xd), np.int64)

@@ -317,12 +317,15 @@ class EncodePlan(PlanStats):
         import jax
         import numpy as _np
 
+        from .backends import mesh_sharding
+
         q = self.field.q
         spec = self.spec
+        sharding = mesh_sharding(self) if self.backend == "mesh" else None
 
         def to_device(c):
             return jax.device_put(
-                _np.ascontiguousarray(c % q).astype(_np.uint32))
+                _np.ascontiguousarray(c % q).astype(_np.uint32), sharding)
 
         if self.backend == "mesh":
             fn = self.mesh_callable()

@@ -33,11 +33,6 @@ from .field import fermat_add, fermat_mul, fermat_sub
 from .matrices import StructuredPoints, gauss_inverse, vandermonde
 from .prepare_shoot import phase_split
 
-# jax < 0.5 ships shard_map under jax.experimental; newer jax at top level
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map
-
 
 # ---------------------------------------------------------------------------
 # grouped ppermute helper

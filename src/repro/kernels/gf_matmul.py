@@ -11,8 +11,9 @@ Overflow proof (all uint32, no 64-bit — TPU-native):
   * inputs are in [0, q) with q = 2^16 + 1
   * each product is Fermat-reduced *before* accumulation (fermat_mul), so
     every addend is < q <= 2^16 + 1
-  * the per-k-step partial sum accumulates bk <= 2^14 addends:
-    2^14 * (2^16) < 2^31  — no uint32 wrap, then one fermat_reduce
+  * the per-slice partial sum accumulates bk_inner <= 2^14 addends:
+    2^14 * (2^16 + 1) < 2^31, so it is summed exactly in int32 (Mosaic has
+    no unsigned reductions) and cast back to uint32 for one fermat_reduce
   * the running output tile is kept reduced (< q) via modular add.
 
 dtype note: TPU Pallas prefers >=2D int32/uint32 tiles with last dim 128; we
@@ -65,8 +66,8 @@ def _gf_matmul_kernel(a_ref, b_ref, o_ref, *, bk_inner: int):
         a_sl = a[:, s : s + bk_inner]           # (bm, ki)
         b_sl = b[s : s + bk_inner, :]           # (ki, bn)
         prods = _fermat_mul_u32(a_sl[:, :, None], b_sl[None, :, :])
-        # every addend < q <= 2^16+1; ki <= 2^14 => sum < 2^31: no wrap
-        part = jnp.sum(prods, axis=1, dtype=jnp.uint32)
+        # every addend < q <= 2^16+1; ki <= 2^14 => sum < 2^31: exact in int32
+        part = jnp.sum(prods.astype(jnp.int32), axis=1).astype(jnp.uint32)
         acc = _fermat_add_u32(acc, _fermat_reduce_u32(part))
     o_ref[...] = acc
 
@@ -79,24 +80,21 @@ def _pad_to(x, mult0, mult1):
     return x
 
 
-@functools.partial(
-    jax.jit, static_argnames=("bm", "bn", "bk", "bk_inner", "interpret")
-)
-def gf_matmul(
+def gf_matmul_pallas(
     a: jnp.ndarray,
     b: jnp.ndarray,
     *,
+    interpret: bool,
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
     bk_inner: int = 8,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(a @ b) mod 65537 with explicit VMEM tiling.
 
     a: (M, K), b: (K, N), any uint32-compatible dtype with values in [0, q).
-    interpret=True executes the kernel body in Python on CPU (this container
-    is CPU-only; TPU is the lowering target).
+    Callers go through `kernels.ops.gf_matmul`, which sets `interpret` from
+    the platform the program is lowered for.
     """
     a = a.astype(jnp.uint32)
     b = b.astype(jnp.uint32)

@@ -8,11 +8,12 @@ Erasure decode of a systematic code [I | A] is a two-step computation:
   2. apply it to the (K, W) survivor payloads, W up to millions of symbols:
      x = (S^T)^-1 v, and lost symbols y_E = (S^-1 G[:, E])^T v.
 
-Step 2 is a field matmul and runs on the same Pallas `gf_matmul` kernel as
-the encode path (VMEM-tiled, uint32-only — see `gf_matmul.py` for the
-overflow proof); step 1 is an exact Gauss-Jordan elimination over F_65537
-implemented here directly on the jnp uint32 path (no int64 anywhere, so the
-same code lowers on TPU), with the numpy `core.matrices.gauss_inverse` as
+Step 2 is a field matmul and runs through the same `ops.field_matmul`
+dispatch as the encode path (the VMEM-tiled Pallas kernel for large
+operands, uint32-only — see `gf_matmul.py` for the overflow proof);
+step 1 is an exact Gauss-Jordan elimination over F_65537 implemented
+here directly on the jnp uint32 path (no int64 anywhere, so the same code
+lowers on TPU), with the numpy `core.matrices.gauss_inverse` as
 its host oracle.  The inverse of a nonsingular matrix is unique, so both
 paths are bitwise identical.
 
@@ -29,10 +30,7 @@ import numpy as np
 
 from repro.core.field import FERMAT_Q, fermat_mul, fermat_sub
 
-from .gf_matmul import gf_matmul
-from .ref import gf_matmul_ref
-
-_PALLAS_MIN_DIM = 128
+from .ops import field_matmul
 
 
 def _as_field_u32(x) -> jnp.ndarray:
@@ -87,20 +85,10 @@ def gf_gauss_inverse(a: jnp.ndarray) -> jnp.ndarray:
     return inv
 
 
-def gf_apply(a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
-    """(a @ b) mod 65537 on the Pallas kernel for large operands, jnp ref
-    below the tile threshold (kernel launch overhead dominates there)."""
-    a = a.astype(jnp.uint32)
-    b = b.astype(jnp.uint32)
-    if min(a.shape + b.shape) >= _PALLAS_MIN_DIM:
-        return gf_matmul(a, b, interpret=interpret)
-    return gf_matmul_ref(a, b)
-
-
-def gf_solve(a, b, *, interpret: bool = True) -> jnp.ndarray:
+def gf_solve(a, b) -> jnp.ndarray:
     """Solve a @ x = b over F_65537: x = a^-1 b, exact.
 
     a: (n, n), b: (n, W) — the decode use is a = S^T (survivor submatrix,
     transposed) and b the survivor payloads, giving the original data x.
     """
-    return gf_apply(gf_gauss_inverse(a), _as_field_u32(b), interpret=interpret)
+    return field_matmul(gf_gauss_inverse(a), _as_field_u32(b))

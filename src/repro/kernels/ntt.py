@@ -9,9 +9,15 @@ Sec. V-A produces the same permutation; validated in tests against
 payload column is one lane, so a (K, W) tile is transformed in
 O(K log K · W) field ops instead of the O(K^2 · W) matmul.
 
-VMEM layout: one (K, bw) tile resident across all log2(K) stages
-(K <= 4096, bw = 128 -> 2 MiB uint32); twiddles (H, K/2) ride along.
-All arithmetic is the uint32 Fermat-prime path — no 64-bit, TPU-native.
+Kernel layout: one (rows, bw) tile stays in VMEM across all log2(K)
+stages.  Each stage pairs row i with row i XOR half through two sublane
+rolls and a row-parity select, so no stage slices, concatenates or
+reshapes across a sublane tile, and each stage's twiddles are one column
+of a host-built (rows, H) table (1 on the rows that take no twiddle).  For
+K < 8 the wrapper stacks 8/K column blocks into the sublanes, so every
+tile holds at least one full (8, 128) register.  On a v5e the compiled
+kernel fits VMEM up to K = 2048.  All arithmetic is the
+uint32 Fermat-prime path — no 64-bit, TPU-native.
 """
 from __future__ import annotations
 
@@ -25,6 +31,10 @@ from jax.experimental import pallas as pl
 
 from repro.core.field import FERMAT, FERMAT_Q
 from .gf_matmul import _fermat_add_u32, _fermat_mul_u32
+
+_SUBLANES = 8           # rows of one uint32 register tile
+_LANES = 128
+_BLOCK_ELEMS = 1 << 16  # elements of one (rows, bw) tile: 256 KiB uint32
 
 
 def ntt_twiddles(K: int, inverse: bool = False) -> np.ndarray:
@@ -50,8 +60,9 @@ def _ntt_stages(x, tw, *, K: int, inverse: bool):
     """DIF butterflies forward; stage-wise inverse (DIT form, inverse
     twiddles, reversed stage order) for the inverse transform.
 
-    Shared by the Pallas kernel body and the fused-XLA path (`ntt_xla`):
-    all arithmetic is exact uint32 mod-q, so the two are bitwise-equal.
+    The fused-XLA body (`ntt_xla`).  The Pallas kernel runs the same
+    butterflies on its own layout (`_ntt_kernel`); all arithmetic is exact
+    uint32 mod-q, so the two are bitwise-equal.
     x: (K, bw) uint32 values; tw: (H, K/2) uint32 twiddles.
     """
     H = int(math.log2(K))
@@ -77,54 +88,98 @@ def _ntt_stages(x, tw, *, K: int, inverse: bool):
     return x
 
 
+def _row_twiddles(K: int, rows: int, inverse: bool) -> np.ndarray:
+    """(rows, H) twiddle table for the kernel's row layout: column h is
+    stage h's per-row twiddle.
+
+    Row i holds point i % K of its transform.  At stage h (half = K >>
+    (h+1)) the row takes `ntt_twiddles(K)[h]` at its butterfly index when
+    bit `half` of i is set, and 1 otherwise.  The inverse transform's
+    first stage also carries the 1/K scale: every row passes through that
+    multiply, and the transform is linear.
+    """
+    tw = ntt_twiddles(K, inverse=inverse)
+    H = tw.shape[0]
+    i = np.arange(rows) % K
+    out = np.ones((rows, H), np.int64)
+    for h in range(H):
+        half = K >> (h + 1)
+        take = (i & half) != 0
+        out[take, h] = tw[h, (i[take] // (2 * half)) * half + i[take] % half]
+    if inverse:
+        out[:, H - 1] = out[:, H - 1] * pow(K, FERMAT_Q - 2, FERMAT_Q) % FERMAT_Q
+    return out.astype(np.uint32)
+
+
 def _ntt_kernel(x_ref, tw_ref, o_ref, *, K: int, inverse: bool):
-    o_ref[...] = _ntt_stages(x_ref[...].astype(jnp.uint32), tw_ref[...],
-                             K=K, inverse=inverse)
+    """One (rows, bw) tile through every stage.  Row i's butterfly partner
+    is row i XOR half: `up[i] = x[i + half]` serves the rows with that bit
+    clear (u), `dn[i] = x[i - half]` the rows with it set (v)."""
+    x = x_ref[...]
+    rows = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    H = int(math.log2(K))
+    for h in (range(H - 1, -1, -1) if inverse else range(H)):
+        half = K >> (h + 1)
+        is_v = (row & half) != 0
+        w = tw_ref[:, h:h + 1]                            # (rows, 1)
+        if inverse:
+            # u' = u + v*w, v' = u - v*w: scale the v rows, then combine
+            x = _fermat_mul_u32(x, w)
+        up = jnp.roll(x, rows - half, axis=0)
+        dn = jnp.roll(x, half, axis=0)
+        x = jnp.where(is_v, _fermat_sub_u32(dn, x), _fermat_add_u32(x, up))
+        if not inverse:
+            # u' = u + v, v' = (u - v)*w: combine, then scale the v rows
+            x = _fermat_mul_u32(x, w)
+    o_ref[...] = x
 
 
-@functools.partial(jax.jit, static_argnames=("inverse", "bw", "interpret"))
-def ntt(x: jnp.ndarray, *, inverse: bool = False, bw: int = 128,
-        interpret: bool = True) -> jnp.ndarray:
+def ntt_pallas(x: jnp.ndarray, *, inverse: bool, interpret: bool
+               ) -> jnp.ndarray:
     """Batched NTT along axis 0: x (K, W) uint32 in [0, q).
 
     Forward: out[k] = sum_j x[j] * beta^(j * rev(k))   (== x @ D_K Pi).
     Inverse: exact inverse of forward (includes the 1/K scaling).
+    Callers go through `kernels.ops.ntt`, which sets `interpret` from the
+    platform the program is lowered for.
     """
     x = x.astype(jnp.uint32)
     K, W = x.shape
     H = int(math.log2(K))
-    assert 2**H == K, "K must be a power of two"
-    tw = jnp.asarray(ntt_twiddles(K, inverse=inverse))
-
-    pad = (-W) % bw
-    xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
-    Wp = xp.shape[1]
+    assert K >= 2 and 2**H == K, "K must be a power of two >= 2"
+    g = max(1, _SUBLANES // K)          # column blocks stacked per tile
+    rows = g * K
+    wg = -(-W // g)
+    bw = max(_LANES, min(_BLOCK_ELEMS // rows, -(-wg // _LANES) * _LANES)
+             // _LANES * _LANES)
+    wp = -(-wg // bw) * bw
+    xp = jnp.pad(x, ((0, 0), (0, g * wp - W)))
+    if g > 1:
+        xp = xp.reshape(K, g, wp).transpose(1, 0, 2).reshape(rows, wp)
     out = pl.pallas_call(
         functools.partial(_ntt_kernel, K=K, inverse=inverse),
-        grid=(Wp // bw,),
+        grid=(wp // bw,),
         in_specs=[
-            pl.BlockSpec((K, bw), lambda w: (0, w)),
-            pl.BlockSpec((H, K // 2), lambda w: (0, 0)),
+            pl.BlockSpec((rows, bw), lambda w: (0, w)),
+            pl.BlockSpec((rows, H), lambda w: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((K, bw), lambda w: (0, w)),
-        out_shape=jax.ShapeDtypeStruct((K, Wp), jnp.uint32),
+        out_specs=pl.BlockSpec((rows, bw), lambda w: (0, w)),
+        out_shape=jax.ShapeDtypeStruct((rows, wp), jnp.uint32),
         interpret=interpret,
-    )(xp, tw)
-    out = out[:, :W]
-    if inverse:
-        kinv = jnp.uint32(pow(K, FERMAT_Q - 2, FERMAT_Q))
-        out = _fermat_mul_u32(out, kinv)
-    return out
+    )(xp, jnp.asarray(_row_twiddles(K, rows, inverse)))
+    if g > 1:
+        out = out.reshape(g, K, wp).transpose(1, 0, 2).reshape(K, g * wp)
+    return out[:, :W]
 
 
 @functools.partial(jax.jit, static_argnames=("inverse",))
 def ntt_xla(x: jnp.ndarray, *, inverse: bool = False) -> jnp.ndarray:
     """`ntt` as one fused XLA computation (no pallas_call, no grid).
 
-    Bitwise-identical to the Pallas kernel (same `_ntt_stages` body, exact
-    integer arithmetic) but without the per-grid-step interpreter overhead —
-    on CPU this is the throughput path; on TPU the Pallas kernel with its
-    explicit VMEM residency is preferred (see `ntt_auto`).
+    Bitwise-identical to the Pallas kernel (exact integer arithmetic) but
+    without the per-grid-step interpreter overhead, so it is the CPU path
+    of `kernels.ops.ntt_auto`.
     """
     x = x.astype(jnp.uint32)
     K = x.shape[0]
@@ -135,14 +190,6 @@ def ntt_xla(x: jnp.ndarray, *, inverse: bool = False) -> jnp.ndarray:
         kinv = jnp.uint32(pow(K, FERMAT_Q - 2, FERMAT_Q))
         out = _fermat_mul_u32(out, kinv)
     return out
-
-
-def ntt_auto(x: jnp.ndarray, *, inverse: bool = False) -> jnp.ndarray:
-    """Backend-appropriate NTT: the Pallas kernel on TPU (compiled, VMEM
-    tiling), the fused-XLA path elsewhere.  Traceable under jit."""
-    if jax.default_backend() == "tpu":
-        return ntt(x, inverse=inverse, interpret=False)
-    return ntt_xla(x, inverse=inverse)
 
 
 def ntt_ref(x: jnp.ndarray, inverse: bool = False) -> np.ndarray:

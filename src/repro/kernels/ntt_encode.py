@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.field import FERMAT_Q, fermat_mul, fermat_reduce
-from .ntt import ntt_auto
+from .ops import ntt_auto
 
 
 def _pow_vec(base: int, n: int, q: int) -> np.ndarray:

@@ -315,6 +315,9 @@ def main():
     args = ap.parse_args()
     if args.degraded and not args.coded_selfcheck:
         ap.error("--degraded modifies the self-check; pass --coded-selfcheck")
+    from ..compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     tracer = None
     if args.trace:
         from ..obs import trace as _trace
@@ -376,8 +379,10 @@ def _run(args, ap):
         out.append(tok)
     toks = jnp.stack(out, 1)
     dt = (time.time() - t0) / (toks.shape[1] - 1) * 1e3
+    dev = jax.devices()[0]
     print(f"arch={cfg.name} batch={B} generated {args.gen_len} tokens/seq "
-          f"@ {dt:.1f} ms/token (CPU, reduced config)")
+          f"@ {dt:.1f} ms/token ({dev.platform} {dev.device_kind}, "
+          "reduced config)")
     print("sample token ids:", toks[0, args.prompt_len:args.prompt_len + 12].tolist())
 
 

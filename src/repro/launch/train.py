@@ -17,7 +17,6 @@ Fault tolerance:
     every step decodes around the injected straggler mask
     (--straggler-mode random|bursty|fixed) with bitwise-exact gradients —
     --straggler-selfcheck asserts that against the all-alive step
-  * XLA latency-hiding scheduler flags enabled for compute/comm overlap.
 """
 from __future__ import annotations
 
@@ -69,9 +68,6 @@ def main():
         os.environ["XLA_FLAGS"] = (
             "--xla_force_host_platform_device_count=512 "
             + os.environ.get("XLA_FLAGS", ""))
-    # compute/comm overlap: async collectives + latency-hiding scheduling
-    os.environ.setdefault("XLA_FLAGS", "")
-    os.environ["XLA_FLAGS"] += " --xla_cpu_use_thunk_runtime=true"
 
     import dataclasses
 
@@ -81,12 +77,14 @@ def main():
 
     from ..ckpt import CodedCheckpointer
     from ..coding import GradientCoder
+    from ..compile_cache import enable_compile_cache
     from ..configs import get_config
     from ..data import SyntheticLM
     from ..train import (StragglerInjector, init_state,
                          make_straggler_train_step, make_train_setup,
                          make_train_step)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

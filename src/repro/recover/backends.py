@@ -67,6 +67,17 @@ def run_local(plan, v: np.ndarray) -> np.ndarray:
     return np.asarray(y, np.int64)
 
 
+def mesh_sharding(plan):
+    """Survivor i's row on device i: the layout the mesh callables
+    consume, placed straight from the host."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ..api.backends import _require_devices
+
+    return NamedSharding(Mesh(np.array(_require_devices(plan.spec.K)),
+                              ("dec",)), P("dec"))
+
+
 def _mesh_callables(plan) -> list:
     """One jitted shard_map executable per repair batch, kept for the
     plan's lifetime (same caching contract as `EncodePlan.mesh_callable`).
@@ -77,18 +88,15 @@ def _mesh_callables(plan) -> list:
     """
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
 
-    from ..api.backends import _require_devices
     from ..core.parity import mesh_parity_encode
-    from ..core.shardmap_exec import shard_map
 
     if plan._mesh_fns is not None:
         return plan._mesh_fns
 
-    spec = plan.spec
-    devs = _require_devices(spec.K)
-    mesh = Mesh(np.array(devs), ("dec",))
+    mesh = mesh_sharding(plan).mesh
 
     def _batch_fn(t):
         arrs = t.device_arrays()
@@ -111,10 +119,11 @@ def _mesh_callables(plan) -> list:
 
 
 def run_mesh(plan, v: np.ndarray) -> np.ndarray:
-    import jax.numpy as jnp
+    import jax
 
     q = plan.field.q
-    vg = jnp.asarray(np.asarray(v) % q, jnp.uint32)
+    vg = jax.device_put((np.asarray(v) % q).astype(np.uint32),
+                        mesh_sharding(plan))
     out = []
     with kernel_span("mesh_decode", kind=plan.spec.kind, K=plan.spec.K,
                      E=len(plan.erased), w=int(vg.shape[1])):

@@ -273,13 +273,15 @@ class DecodePlan(PlanStats):
         import jax
         import numpy as _np
 
-        from .backends import _mesh_callables, local_decode_callable
+        from .backends import (_mesh_callables, local_decode_callable,
+                               mesh_sharding)
 
         q = self.field.q
+        sharding = mesh_sharding(self) if self.backend == "mesh" else None
 
         def to_device(c):
             return jax.device_put(
-                _np.ascontiguousarray(c % q).astype(_np.uint32))
+                _np.ascontiguousarray(c % q).astype(_np.uint32), sharding)
 
         if self.backend == "mesh":
             fns = _mesh_callables(self)

@@ -13,6 +13,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.field import FERMAT
@@ -23,7 +24,6 @@ from repro.core.shardmap_exec import (
     build_universal_tables,
     mesh_dft,
     mesh_universal_a2a,
-    shard_map,
 )
 
 f = FERMAT

@@ -5,8 +5,7 @@ import pytest
 from conftest_hypothesis import given, settings, st
 
 from repro.core.field import FERMAT, FERMAT_Q
-from repro.kernels.gf_matmul import gf_matmul
-from repro.kernels.ops import encode_blocks
+from repro.kernels.ops import encode_blocks, gf_matmul
 from repro.kernels.ref import gf_matmul_ref
 
 RNG = np.random.default_rng(11)
@@ -93,7 +92,8 @@ def test_encode_blocks_dispatch():
 
 @pytest.mark.parametrize("K", [4, 16, 64, 256, 1024])
 def test_ntt_kernel_vs_permuted_dft(K):
-    from repro.kernels.ntt import ntt, ntt_ref
+    from repro.kernels.ntt import ntt_ref
+    from repro.kernels.ops import ntt
 
     x = RNG.integers(0, FERMAT_Q, (K, 6)).astype(np.uint32)
     got = np.asarray(ntt(jnp.asarray(x)))
@@ -102,7 +102,7 @@ def test_ntt_kernel_vs_permuted_dft(K):
 
 @pytest.mark.parametrize("K", [16, 128])
 def test_ntt_roundtrip_and_padding(K):
-    from repro.kernels.ntt import ntt
+    from repro.kernels.ops import ntt
 
     x = RNG.integers(0, FERMAT_Q, (K, 131)).astype(np.uint32)  # W % bw != 0
     y = ntt(jnp.asarray(x))
@@ -111,7 +111,8 @@ def test_ntt_roundtrip_and_padding(K):
 
 
 def test_ntt_adversarial_values():
-    from repro.kernels.ntt import ntt, ntt_ref
+    from repro.kernels.ntt import ntt_ref
+    from repro.kernels.ops import ntt
 
     x = np.full((64, 4), FERMAT_Q - 1, np.uint32)
     assert np.array_equal(np.asarray(ntt(jnp.asarray(x))), ntt_ref(jnp.asarray(x)))

@@ -61,3 +61,35 @@ def test_serve_launcher():
                  "--batch", "2", "--prompt-len", "8", "--gen-len", "8"])
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "generated 8 tokens/seq" in proc.stdout
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself and the
+    helper sets no path of its own."""
+    import jax
+
+    from repro.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_fixed_and_ignored(monkeypatch):
+    """Unset, the cache goes to one fixed, git-ignored path in the
+    checkout."""
+    import jax
+
+    from repro.compile_cache import enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert enable_compile_cache() == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert Path(path) == REPO / ".jax_cache"
+    assert ".jax_cache/" in (REPO / ".gitignore").read_text().split()
