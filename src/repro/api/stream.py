@@ -38,7 +38,7 @@ from typing import Callable, Iterable, Iterator
 import numpy as np
 
 from ..obs.metrics import REGISTRY as _METRICS
-from ..obs.trace import get_tracer
+from ..obs.trace import stage
 
 DEFAULT_VMEM_BUDGET_BYTES = 4 << 20  # (K, w) uint32 payload tile budget
 _LANES = 128                         # TPU register lane width
@@ -143,9 +143,14 @@ def run_paired_stream(plan, chunks: Iterator[np.ndarray], slice_fn: Callable,
         yield pending.popleft(), y
 
 
+def _nbytes(y) -> int:
+    """Bytes of a device result: one array, or a mesh decode's list."""
+    return sum(a.nbytes for a in y) if isinstance(y, list) else y.nbytes
+
+
 def _pipelined(chunks: Iterator[np.ndarray], to_device: Callable,
-               dev_fn: Callable, finalize: Callable,
-               tracer=None) -> Iterator[np.ndarray]:
+               dev_fn: Callable, finalize: Callable, *, op: str,
+               backend: str) -> Iterator[np.ndarray]:
     """Double-buffered device pipeline.
 
     For each chunk: dispatch compute on the resident buffer, enqueue the
@@ -154,51 +159,35 @@ def _pipelined(chunks: Iterator[np.ndarray], to_device: Callable,
     the k compute, and the jitted callable's buffers turn over without a
     host sync between chunks.
 
-    With a `tracer`, the three pipeline stages of every chunk become
-    spans on a "stream"/"pipeline" track (h2d / dispatch / materialize);
-    the untraced loop is the byte-identical fast path.
+    The three pipeline stages of every chunk (h2d / dispatch /
+    materialize) are `obs.trace.stage`s labelled `op` and `backend`.
     """
-    if tracer is None:
-        cur = None
-        for c in chunks:
-            if cur is None:
-                cur = to_device(c)
-                continue
-            y = dev_fn(cur)          # async dispatch of chunk k
-            cur = to_device(c)       # H2D of chunk k+1 overlaps the compute
-            yield finalize(y)        # block on chunk k only now
-        if cur is not None:
-            yield finalize(dev_fn(cur))
-        return
+    def _h2d(c):
+        with stage("h2d", op=op, backend=backend) as s:
+            d = to_device(c)
+            s.moved(d.nbytes)
+        return d
 
-    def _span(name, k):
-        return tracer.span(name, pid="stream", tid="pipeline",
-                           cat="stream", args={"chunk": k})
+    def _run(d):
+        with stage("dispatch", op=op, backend=backend):
+            return dev_fn(d)
+
+    def _materialize(y):
+        with stage("materialize", op=op, backend=backend) as s:
+            out = finalize(y)
+            s.moved(_nbytes(y))
+        return out
 
     cur = None
-    k = 0          # index of the chunk resident on device
-    n = 0          # index of the chunk being transferred
     for c in chunks:
         if cur is None:
-            with _span("h2d", n):
-                cur = to_device(c)
-            n += 1
+            cur = _h2d(c)
             continue
-        with _span("dispatch", k):
-            y = dev_fn(cur)
-        with _span("h2d", n):
-            cur = to_device(c)
-        with _span("materialize", k):
-            out = finalize(y)
-        yield out
-        k += 1
-        n += 1
+        y = _run(cur)            # async dispatch of chunk k
+        cur = _h2d(c)            # H2D of chunk k+1 overlaps the compute
+        yield _materialize(y)    # block on chunk k only now
     if cur is not None:
-        with _span("dispatch", k):
-            y = dev_fn(cur)
-        with _span("materialize", k):
-            out = finalize(y)
-        yield out
+        yield _materialize(_run(cur))
 
 
 def run_stream(plan, payload, *, chunk_w: int | None = None
@@ -240,7 +229,7 @@ def run_stream(plan, payload, *, chunk_w: int | None = None
     if backend.supports_stream:
         to_device, dev_fn, finalize = plan._stream_device_fn()
         yield from _pipelined(chunks, to_device, dev_fn, finalize,
-                              tracer=get_tracer())
+                              op=plan.op, backend=plan.backend)
         return
     run_chunk = backend.encode if plan.op == "encode" else backend.decode
     for c in chunks:

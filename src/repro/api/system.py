@@ -45,6 +45,7 @@ from typing import Any, Iterable, Iterator
 
 import numpy as np
 
+from ..obs.trace import stage
 from .planner import ALPHA_DEFAULT, BETA_BITS_DEFAULT, EncodePlan, Encoder
 from .registry import get_backend
 from .spec import CodeSpec
@@ -119,9 +120,9 @@ class CodedSystem:
     trace   : observability tracer — True (collect, read
               `system.tracer`), an `obs.trace.Tracer`, or a path (trace
               JSON written there on `close()`).  Installed process-wide
-              for the session's lifetime, so simulator rounds, stream
-              pipeline stages, and kernel launches under this session
-              all land on one timeline.
+              for the session's lifetime, so simulator rounds and the
+              host-edge stages of every call (`obs.trace.stage`) under
+              this session all land on one timeline.
     """
 
     def __init__(self, spec: CodeSpec, backend: str = "simulator", *,
@@ -286,15 +287,19 @@ class CodedSystem:
         return self._enc.run_batched(xs, chunk_w=chunk_w or self.chunk_w)
 
     # -- decode / degraded read ---------------------------------------------
-    def _survivor_view(self, v, plan) -> np.ndarray:
+    def _survivor_view(self, v, plan, op: str,
+                       backend: str | None = None) -> np.ndarray:
         """Normalize (N, ...) codeword rows or (K, ...) kept-ordered
         survivor symbols to the (K, ...) form `plan` consumes.  The plan
         is passed in (not re-resolved from the live erasure state) so one
         operation slices and executes against ONE pattern even if a
-        concurrent `fail`/`heal` lands mid-flight."""
+        concurrent `fail`/`heal` lands mid-flight.  Gathering the K rows
+        out of N is the `gather` stage of `op` on `backend` (the plan's
+        unless given; `obs.trace.stage`)."""
         v = np.asarray(v)
         if v.shape[0] == self.spec.N:
-            return v[list(plan.kept)]
+            with stage("gather", op=op, backend=backend or plan.backend):
+                return v[list(plan.kept)]
         if v.shape[0] == self.spec.K:
             return v
         raise ValueError(
@@ -307,7 +312,7 @@ class CodedSystem:
         returns (|failed|,)/(|failed|, W) rows ordered like
         `system.failed` (empty while healthy)."""
         plan = self.decode_plan  # pinned: one pattern for slice + run
-        return plan.run(self._survivor_view(v, plan))
+        return plan.run(self._survivor_view(v, plan, "decode"))
 
     def read(self, v) -> np.ndarray:
         """Degraded read: the full original data (K,)/(K, W) from the
@@ -321,7 +326,8 @@ class CodedSystem:
                     f" ...) rows, got leading dim {v.shape[0]}")
             return (v[: self.spec.K] % self.spec.q).astype(np.int64)
         plan = self.decode_plan  # pinned: one pattern for slice + data
-        return plan.data(self._survivor_view(v, plan))
+        # `DecodePlan.data` runs on one device whatever the backend
+        return plan.data(self._survivor_view(v, plan, "read", "local"))
 
     def decode_stream(self, payload, *, chunk_w: int | None = None
                       ) -> Iterator[np.ndarray]:
@@ -336,7 +342,7 @@ class CodedSystem:
 
         def _sliced():
             for piece in pieces:
-                yield self._survivor_view(piece, plan)
+                yield self._survivor_view(piece, plan, "decode")
 
         return plan.run_stream(_sliced(), chunk_w=chunk_w or self.chunk_w)
 

@@ -20,6 +20,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -206,22 +207,29 @@ def mesh_parity_encode(x, rows: dict, t: ParityTables, axis_name: str):
     v = x.astype(jnp.uint32)
 
     # ---- phase 1: column-wise A2A on A_m ---------------------------------
+    # each transform and each reduce round is a named scope, so device ops
+    # in a profile name the round they belong to
     if t.method == "universal":
-        v = mesh_universal_a2a(v, rows["u_coef"], rows["u_corr"], t.univ, axis_name)
+        with jax.named_scope("parity.universal"):
+            v = mesh_universal_a2a(v, rows["u_coef"], rows["u_corr"], t.univ, axis_name)
     else:
         v = fermat_mul(rows["pre"], v)
         # inverse draw-and-loose on V_{alpha,m}
         if t.dl_inv_dft is not None:
-            v = mesh_dft(v, rows["i_ca"], rows["i_cb"], t.dl_inv_dft, axis_name, inverse=True)
+            with jax.named_scope("parity.inv_dft"):
+                v = mesh_dft(v, rows["i_ca"], rows["i_cb"], t.dl_inv_dft, axis_name, inverse=True)
         v = fermat_mul(rows["i_scale"], v)
         if t.dl_inv_univ is not None:
-            v = mesh_universal_a2a(v, rows["i_coef"], rows["i_corr"], t.dl_inv_univ, axis_name)
+            with jax.named_scope("parity.inv_universal"):
+                v = mesh_universal_a2a(v, rows["i_coef"], rows["i_corr"], t.dl_inv_univ, axis_name)
         # forward draw-and-loose on V_beta
         if t.dl_fwd_univ is not None:
-            v = mesh_universal_a2a(v, rows["f_coef"], rows["f_corr"], t.dl_fwd_univ, axis_name)
+            with jax.named_scope("parity.fwd_universal"):
+                v = mesh_universal_a2a(v, rows["f_coef"], rows["f_corr"], t.dl_fwd_univ, axis_name)
         v = fermat_mul(rows["f_scale"], v)
         if t.dl_fwd_dft is not None:
-            v = mesh_dft(v, rows["f_ca"], rows["f_cb"], t.dl_fwd_dft, axis_name, inverse=False)
+            with jax.named_scope("parity.fwd_dft"):
+                v = mesh_dft(v, rows["f_ca"], rows["f_cb"], t.dl_fwd_dft, axis_name, inverse=False)
         v = fermat_mul(rows["post"], v)
 
     # ---- phase 2: row-wise reduce onto column 0 ---------------------------
@@ -230,10 +238,11 @@ def mesh_parity_encode(x, rows: dict, t: ParityTables, axis_name: str):
     for tt in range(1, T_red + 1):
         sub = (p + 1) ** (tt - 1)
         for rho in range(1, p + 1):
-            perm = _group_perm(N, R, M, -rho * sub)
-            recv = _ppermute(v, axis_name, perm)
-            m_row = rows["reduce_mask"][tt - 1, rho - 1]
-            v = fermat_add(v, fermat_mul(m_row, recv))
+            with jax.named_scope(f"parity.reduce_{tt}_{rho}"):
+                perm = _group_perm(N, R, M, -rho * sub)
+                recv = _ppermute(v, axis_name, perm)
+                m_row = rows["reduce_mask"][tt - 1, rho - 1]
+                v = fermat_add(v, fermat_mul(m_row, recv))
     return v
 
 

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -123,6 +124,7 @@ class NTTEncodeParams:
                                phi_inv, psi, twist)
 
 
+@jax.named_scope("ntt_encode")
 def ntt_encode(x: jnp.ndarray, params: NTTEncodeParams) -> jnp.ndarray:
     """Encode payload x (K, W) uint32 -> sink values (R, W) uint32.
 

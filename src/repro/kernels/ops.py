@@ -60,6 +60,7 @@ def ntt_auto(x: jnp.ndarray, *, inverse: bool = False) -> jnp.ndarray:
 
 
 @jax.jit
+@jax.named_scope("field_matmul")
 def field_matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """(a @ b) mod 65537: the Pallas kernel when every dimension is at
     least `_PALLAS_MIN_DIM`, the jnp reference otherwise."""
@@ -70,6 +71,7 @@ def field_matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return gf_matmul_ref(a, b)
 
 
+@jax.named_scope("encode_blocks")
 def encode_blocks(x: jnp.ndarray, coeffs: jnp.ndarray) -> jnp.ndarray:
     """y = x^T-style field encode: (S, W) data against (S, T) coefficients.
 
@@ -78,6 +80,7 @@ def encode_blocks(x: jnp.ndarray, coeffs: jnp.ndarray) -> jnp.ndarray:
     return field_matmul(coeffs.T, x)
 
 
+@jax.named_scope("decode_blocks")
 def decode_blocks(v: jnp.ndarray, dmat: jnp.ndarray) -> jnp.ndarray:
     """Apply a precomputed decode matrix to survivor payloads.
 

@@ -7,9 +7,12 @@ asserting them in tests:
 
     trace   — a low-overhead span/event tracer with Chrome trace-event
               JSON export (perfetto / chrome://tracing).  The simulator
-              emits per-round events on per-processor tracks, the stream
-              engine emits H2D/compute pipeline spans, and the queue /
-              service layers emit per-op spans tagged tenant/tag/group.
+              emits per-round events on per-processor tracks and the
+              queue / service layers emit per-op spans tagged
+              tenant/tag/group.  `trace.stage` times the API's host edge
+              (gather, prep, h2d, dispatch, d2h, widen) always:
+              profiler annotations plus `edge_stage_seconds` and
+              `edge_bytes_total` in the registry.
     metrics — ONE labeled counter/gauge/histogram registry the layer
               stats classes (`RunStats`, `PlanStats`, `StreamStats`,
               `QueueStats`, `ServiceStats`) publish into, snapshottable
@@ -20,8 +23,9 @@ asserting them in tests:
               (spec, backend, op, method).
 
 This package is a LEAF: it imports nothing from the rest of `repro` at
-module scope (the drift ledger pulls the cost model lazily, per call), so
-`core.simulator` and `api.registry` may import it without cycles.
+module scope (the drift ledger pulls the cost model lazily, per call,
+and a stage JAX's profiler on its first use), so `core.simulator` and
+`api.registry` may import it without cycles.
 """
 from . import drift, metrics, trace
 from .drift import LEDGER, DriftLedger

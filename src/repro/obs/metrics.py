@@ -17,7 +17,13 @@ exposition format, `render_text`).
 Metric objects are cheap label-resolving handles; values live in the
 registry under (name, sorted-label-items) keys behind one lock, so a
 concurrent `snapshot()` always sees a consistent point-in-time tree
-(asserted by the tier-1 consistency hammer).
+(asserted by the tier-1 consistency hammer).  A hot path resolves its
+labels once with `labels(...)` and keeps the bound handle, which then
+pays one lock and one update per call:
+
+    H2D = metrics.REGISTRY.counter("edge_bytes_total").labels(
+        direction="h2d", op="encode", backend="local")
+    H2D.inc(nbytes)
 """
 from __future__ import annotations
 
@@ -41,15 +47,53 @@ class _Metric:
         return tuple(sorted(labels.items()))
 
 
+class _Bound:
+    """One labelset of a family, resolved once (see `labels`)."""
+
+    __slots__ = ("_lock", "_values", "_key")
+
+    def __init__(self, metric: _Metric, labels: dict):
+        self._lock = metric._reg._lock
+        self._values = metric._values
+        self._key = metric._key(labels)
+
+
+class BoundCounter(_Bound):
+    __slots__ = ()
+
+    def inc(self, n: float = 1) -> None:
+        with self._lock:
+            self._values[self._key] = self._values.get(self._key, 0) + n
+
+
+class BoundHistogram(_Bound):
+    __slots__ = ()
+
+    def observe(self, value: float) -> None:
+        with self._lock:
+            agg = self._values.get(self._key)
+            if agg is None:
+                self._values[self._key] = [1, value, value, value]
+            else:
+                agg[0] += 1
+                agg[1] += value
+                if value < agg[2]:
+                    agg[2] = value
+                if value > agg[3]:
+                    agg[3] = value
+
+
 class Counter(_Metric):
     """Monotonically increasing count (ops, rounds, elements, bytes)."""
 
     kind = "counter"
 
+    def labels(self, **labels) -> BoundCounter:
+        """This counter at `labels`, resolved once for a hot path."""
+        return BoundCounter(self, labels)
+
     def inc(self, n: float = 1, **labels) -> None:
-        key = self._key(labels)
-        with self._reg._lock:
-            self._values[key] = self._values.get(key, 0) + n
+        self.labels(**labels).inc(n)
 
 
 class Gauge(_Metric):
@@ -73,19 +117,12 @@ class Histogram(_Metric):
 
     kind = "histogram"
 
+    def labels(self, **labels) -> BoundHistogram:
+        """This histogram at `labels`, resolved once for a hot path."""
+        return BoundHistogram(self, labels)
+
     def observe(self, value: float, **labels) -> None:
-        key = self._key(labels)
-        with self._reg._lock:
-            agg = self._values.get(key)
-            if agg is None:
-                self._values[key] = [1, value, value, value]
-            else:
-                agg[0] += 1
-                agg[1] += value
-                if value < agg[2]:
-                    agg[2] = value
-                if value > agg[3]:
-                    agg[3] = value
+        self.labels(**labels).observe(value)
 
 
 class MetricsRegistry:

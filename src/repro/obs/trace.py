@@ -22,9 +22,15 @@ measurable.
 Track names are strings (`pid="simulator"`, `tid="proc 3"`); the trace
 format wants integers, so the tracer interns them and emits the
 `process_name` / `thread_name` metadata events perfetto uses for labels.
-Timestamps are wall-clock microseconds from one process-wide epoch, so
-simulator rounds, kernel launches, and service op spans line up on a
-single timeline.
+Timestamps are wall-clock microseconds from the tracer's own epoch (the
+moment it was built), so every layer that emits onto one tracer lines up
+on one timeline; two tracers do not share an epoch.
+
+The API's host edge is timed by `stage`, always on and independent of any
+installed tracer: each stage is a `jax.profiler.TraceAnnotation`
+(`edge.<stage>`, on the profiler's clock, which the device ops share), an
+observation of `edge_stage_seconds` in `obs.metrics.REGISTRY`, and, where
+a tracer is installed, a complete event on it.
 """
 from __future__ import annotations
 
@@ -32,6 +38,8 @@ import json
 import threading
 from contextlib import contextmanager
 from time import perf_counter_ns
+
+from .metrics import REGISTRY
 
 
 class Tracer:
@@ -49,7 +57,7 @@ class Tracer:
         self._events: list[dict] = []
         self._pids: dict[str, int] = {}
         self._tids: dict[tuple[int, str], int] = {}
-        # one process-wide epoch so every layer's timestamps align
+        # this tracer's epoch: every layer emitting onto it aligns
         self._t0 = perf_counter_ns()
 
     def __len__(self) -> int:
@@ -58,7 +66,11 @@ class Tracer:
 
     def now_us(self) -> float:
         """Microseconds since this tracer's epoch (wall clock)."""
-        return (perf_counter_ns() - self._t0) / 1e3
+        return self.at_us(perf_counter_ns())
+
+    def at_us(self, t_ns: int) -> float:
+        """A `perf_counter_ns()` reading on this tracer's clock (us)."""
+        return (t_ns - self._t0) / 1e3
 
     # -- track interning -----------------------------------------------------
     def _pid(self, pid) -> int:
@@ -215,18 +227,89 @@ def installed(tracer: Tracer | None = None):
         uninstall(t)
 
 
-@contextmanager
-def kernel_span(name: str, **args):
-    """Wrap a kernel launch: a tracer span AND a
-    `jax.profiler.TraceAnnotation`, so our spans line up with XLA's own
-    profile when both are captured.  Free (and jax-import-free) when no
-    tracer is installed."""
-    tracer = get_tracer()
-    if tracer is None:
-        yield
-        return
-    from jax.profiler import TraceAnnotation
+# ---------------------------------------------------------------------------
+# host-edge stages: profiler span + registry histogram (+ tracer event)
+# ---------------------------------------------------------------------------
 
-    with tracer.span(name, pid="backend", tid="kernels", cat="kernel",
-                     args=args or None), TraceAnnotation(name):
-        yield
+EDGE_SECONDS = REGISTRY.histogram(
+    "edge_stage_seconds", "host-edge stage wall seconds per call, by "
+    "stage (gather, prep, h2d, dispatch, d2h, widen; materialize in "
+    "streams), op and backend")
+EDGE_BYTES = REGISTRY.counter(
+    "edge_bytes_total", "bytes of the device arrays placed (h2d) or read "
+    "back (d2h) at the host edge, by direction, op and backend")
+
+# the stages whose bytes cross the host-device link, and which way
+_DIRECTION = {"h2d": "h2d", "d2h": "d2h", "materialize": "d2h"}
+
+
+class _Site:
+    """What one (stage, op, backend) resolves once: the span name, the
+    bound registry handles and the tracer event's args."""
+
+    __slots__ = ("span", "seconds", "nbytes", "args", "annotation")
+
+    def __init__(self, name: str, op: str, backend: str):
+        from jax.profiler import TraceAnnotation
+
+        self.span = f"edge.{name}"
+        self.seconds = EDGE_SECONDS.labels(stage=name, op=op,
+                                           backend=backend)
+        direction = _DIRECTION.get(name)
+        self.nbytes = (None if direction is None else EDGE_BYTES.labels(
+            direction=direction, op=op, backend=backend))
+        self.args = {"op": op, "backend": backend}
+        self.annotation = TraceAnnotation
+
+
+_SITES: dict[tuple[str, str, str], _Site] = {}
+
+
+class stage:
+    """Time one host-edge stage of an API call:
+
+        with stage("h2d", op="encode", backend="local") as s:
+            xd = jnp.asarray(x32)
+            s.moved(xd.nbytes)
+
+    The block runs inside `jax.profiler.TraceAnnotation("edge.<name>")`,
+    a constant name, so a profiler trace names the host's share of every
+    device idle gap; its `perf_counter_ns` duration is observed into
+    `edge_stage_seconds{stage, op, backend}`; and where a tracer is
+    installed it also becomes a complete event there (pid "edge").  The
+    labels are resolved once per (name, op, backend), so a stage costs one
+    annotation, two clock reads and one locked update.  `moved(nbytes)`
+    adds to `edge_bytes_total{direction, op, backend}` on the stages that
+    cross the link (h2d; d2h and a stream's materialize)."""
+
+    __slots__ = ("_site", "_ann", "_t0")
+
+    def __init__(self, name: str, *, op: str, backend: str):
+        site = _SITES.get((name, op, backend))
+        if site is None:
+            site = _SITES.setdefault((name, op, backend),
+                                     _Site(name, op, backend))
+        self._site = site
+        self._ann = site.annotation(site.span)
+
+    def __enter__(self) -> "stage":
+        self._ann.__enter__()
+        self._t0 = perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = perf_counter_ns()
+        site = self._site
+        site.seconds.observe((t1 - self._t0) * 1e-9)
+        tracer = get_tracer()
+        if tracer is not None:
+            tracer.complete(site.span, tracer.at_us(self._t0),
+                            (t1 - self._t0) / 1e3, pid="edge", tid="host",
+                            cat="edge", args=dict(site.args))
+        # the annotation closes last, so a profile puts this bookkeeping
+        # under the stage and not in the gap before the next one
+        self._ann.__exit__(*exc)
+
+    def moved(self, nbytes: int) -> None:
+        """Count `nbytes` across the link for this h2d or d2h stage."""
+        self._site.nbytes.inc(nbytes)

@@ -23,7 +23,7 @@ import numpy as np
 
 from ..core import schedule
 from ..core.simulator import RoundNetwork
-from ..obs.trace import kernel_span
+from ..obs.trace import stage
 
 
 def run_simulator(plan, v: np.ndarray) -> tuple[np.ndarray, RoundNetwork]:
@@ -56,15 +56,21 @@ def local_decode_callable(plan):
 
 
 def run_local(plan, v: np.ndarray) -> np.ndarray:
-    """Single-device decode on the Pallas/jnp kernel path (no network)."""
+    """Single-device decode on the Pallas/jnp kernel path (no network);
+    each host-edge stage is an `obs.trace.stage`."""
     import jax.numpy as jnp
 
-    q = plan.field.q
-    v32 = jnp.asarray(np.asarray(v) % q, jnp.uint32)
-    with kernel_span("local_decode", kind=plan.spec.kind, K=plan.spec.K,
-                     E=len(plan.erased), w=int(v32.shape[1])):
-        y = local_decode_callable(plan)(v32)
-    return np.asarray(y, np.int64)
+    from ..api.backends import _finish
+
+    edge = {"op": "decode", "backend": "local"}
+    with stage("prep", **edge):
+        vq = np.asarray(v) % plan.field.q
+        v32 = vq.astype(np.uint32)
+    with stage("h2d", **edge) as s:
+        vd = jnp.asarray(v32)
+        s.moved(vd.nbytes)
+        del v32, vq  # as in `api.backends.run_local`
+    return _finish(local_decode_callable(plan), vd, edge)
 
 
 def mesh_sharding(plan):
@@ -121,13 +127,15 @@ def _mesh_callables(plan) -> list:
 def run_mesh(plan, v: np.ndarray) -> np.ndarray:
     import jax
 
-    q = plan.field.q
-    vg = jax.device_put((np.asarray(v) % q).astype(np.uint32),
-                        mesh_sharding(plan))
-    out = []
-    with kernel_span("mesh_decode", kind=plan.spec.kind, K=plan.spec.K,
-                     E=len(plan.erased), w=int(vg.shape[1])):
-        for fn, (eb, _) in zip(_mesh_callables(plan), plan.tables.batches()):
-            y = np.asarray(fn(vg), np.int64)
-            out.append(y[:eb])
-    return np.concatenate(out, axis=0)
+    from ..api.backends import _finish
+
+    edge = {"op": "decode", "backend": "mesh"}
+    with stage("prep", **edge):
+        v32 = (np.asarray(v) % plan.field.q).astype(np.uint32)
+    with stage("h2d", **edge) as s:
+        vg = jax.device_put(v32, mesh_sharding(plan))
+        s.moved(vg.nbytes)
+        del v32
+    return np.concatenate(
+        [_finish(fn, vg, edge, eb) for fn, (eb, _) in
+         zip(_mesh_callables(plan), plan.tables.batches())], axis=0)

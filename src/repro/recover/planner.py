@@ -41,6 +41,7 @@ from ..core.cost_model import LinearCost
 from ..core.field import FERMAT_Q, Field
 from ..core.matrices import gauss_inverse
 from ..core.simulator import PartialRunError, RoundNetwork
+from ..obs.trace import stage
 from .engine import batch_block, decode_batches, decode_cost
 
 
@@ -308,11 +309,20 @@ class DecodePlan(PlanStats):
         if f.q == FERMAT_Q:
             import jax.numpy as jnp
 
+            from ..api.backends import _finish
             from ..kernels.ops import decode_blocks
 
-            x = np.asarray(decode_blocks(
-                jnp.asarray(v % f.q, jnp.uint32),
-                jnp.asarray(self.tables.Dd % f.q, jnp.uint32)), np.int64)
+            # one device whatever the plan's backend: label what runs
+            edge = {"op": "read", "backend": "local"}
+            with stage("prep", **edge):
+                vq = v % f.q
+                v32 = vq.astype(np.uint32)
+                d32 = (self.tables.Dd % f.q).astype(np.uint32)
+            with stage("h2d", **edge) as s:
+                vd, dd = jnp.asarray(v32), jnp.asarray(d32)
+                s.moved(vd.nbytes + dd.nbytes)
+                del v32, vq  # as in `api.backends.run_local`
+            x = _finish(lambda vd: decode_blocks(vd, dd), vd, edge)
         else:
             x = f.matmul(self.tables.Dd.T, v)
         return x[:, 0] if squeeze else x

@@ -8,6 +8,8 @@ symbols, and degraded reads across all three built-in backends
 codeword AND from (K, W) kept survivors, streamed included) must
 re-materialize the identical codeword on all three, and the mesh
 backend's declared device requirement must be enforced at plan time.
+A mesh encode times each of its host-edge stages once and counts the
+(K, W) uint32 arrays it places and reads back.
 
 Prints 'SYSTEM_MESH_CHECKS_OK' on success; any assertion failure is fatal.
 """
@@ -18,9 +20,23 @@ force_host_devices(8)
 import numpy as np
 
 from repro.api import BackendCapabilityError, CodedSystem, CodeSpec
+from repro.obs.metrics import REGISTRY
 
 f_q = 65537
 rng = np.random.default_rng(31)
+
+
+def edge_delta(before, after, family, **label):
+    """Calls (a histogram's count) or bytes a mesh encode added to
+    `family` at `label` between two registry snapshots."""
+    labels = dict(label, op="encode", backend="mesh")
+    key = ",".join(f"{k}={v}" for k, v in sorted(labels.items()))
+
+    def at(snap):
+        v = snap.get(family, {}).get("values", {}).get(key, 0)
+        return v["count"] if isinstance(v, dict) else v
+    return at(after) - at(before)
+
 
 cases = [
     ("universal", 8, 4, (0, 9)),
@@ -44,8 +60,17 @@ for kind, K, R, erased in cases:
         assert np.array_equal(lost, cw[list(sorted(erased))]), \
             (kind, backend, "decode")
         system.heal()
+        before = REGISTRY.snapshot()
         assert np.array_equal(system.encode(x), cw[K:]), \
             (kind, backend, "re-encode")
+        if backend == "mesh":
+            after = REGISTRY.snapshot()
+            for st in ("prep", "h2d", "dispatch", "d2h", "widen"):
+                assert edge_delta(before, after, "edge_stage_seconds",
+                                  stage=st) == 1, (kind, st)
+            for d in ("h2d", "d2h"):
+                assert edge_delta(before, after, "edge_bytes_total",
+                                  direction=d) == K * 16 * 4, (kind, d)
         # rebuild: recompute ALL failed symbols, return the healed (N, W)
         system.fail(erased)
         assert np.array_equal(system.rebuild(cw), cw), \

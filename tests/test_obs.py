@@ -120,6 +120,137 @@ def test_tracing_off_means_no_tracer_consulted():
 
 
 # ---------------------------------------------------------------------------
+# host-edge stages: profiler spans + registry histograms (+ tracer events)
+# ---------------------------------------------------------------------------
+
+EDGE = ("prep", "h2d", "dispatch", "d2h", "widen")
+W_EDGE = 128
+
+
+def _edge_delta(before, after, family, op, backend):
+    """{stage or direction: count or bytes} added between two snapshots
+    at (op, backend)."""
+    out = {}
+    for key, v in after.get(family, {}).get("values", {}).items():
+        labels = dict(p.split("=", 1) for p in key.split(","))
+        if labels.pop("op") != op or labels.pop("backend") != backend:
+            continue
+        old = before.get(family, {}).get("values", {}).get(key)
+        if isinstance(v, dict):
+            d = v["count"] - (old["count"] if old else 0)
+        else:
+            d = v - (old or 0)
+        if d:
+            out[labels.popitem()[1]] = d
+    return out
+
+
+def _edge_system(op):
+    """A local 6+3 session, failed at data row 2 unless encoding, and the
+    call of `op` with its payload (the full (N, W) codeword)."""
+    system = CodedSystem(_spec("rs", 6, 3), backend="local")
+    x = RNG.integers(0, 1 << 16, (6, W_EDGE))
+    cw = system.codeword(x)
+    if op == "encode":
+        return system, system.encode, x
+    system.fail([2])
+    system.read(cw)              # plans and compiles outside the count
+    system.decode(cw)
+    return system, getattr(system, op), cw
+
+
+# stages in order, and bytes h2d / d2h, of one call at K=6, R=3, |E|=1:
+# the read uploads the 6x6 data matrix (144 B) beside the survivors
+EDGE_CASES = {
+    "encode": (EDGE, 6 * W_EDGE * 4, 3 * W_EDGE * 4),
+    "read": (("gather",) + EDGE, 6 * W_EDGE * 4 + 144, 6 * W_EDGE * 4),
+    "decode": (("gather",) + EDGE, 6 * W_EDGE * 4, 1 * W_EDGE * 4),
+}
+
+
+@pytest.mark.parametrize("op", sorted(EDGE_CASES))
+def test_edge_stages_counted_in_order(op):
+    stages, h2d, d2h = EDGE_CASES[op]
+    system, call, payload = _edge_system(op)
+    before = metrics.REGISTRY.snapshot()
+    with trace.installed() as t:
+        call(payload)
+        call(payload)
+    after = metrics.REGISTRY.snapshot()
+    system.close()
+    # exact counts: every stage once per call, and nothing else
+    assert _edge_delta(before, after, "edge_stage_seconds", op,
+                       "local") == {s: 2 for s in stages}
+    assert _edge_delta(before, after, "edge_bytes_total", op,
+                       "local") == {"h2d": 2 * h2d, "d2h": 2 * d2h}
+    # the installed tracer received the same stages, in order
+    evs = t.events(cat="edge")
+    assert [e["name"] for e in evs] == [f"edge.{s}" for s in stages] * 2
+    assert all(e["args"] == {"op": op, "backend": "local"} for e in evs)
+    assert all(a["ts"] + a["dur"] <= b["ts"] + 1e-3
+               for a, b in zip(evs, evs[1:]))
+
+
+def test_read_stages_labelled_with_the_backend_that_runs():
+    """`DecodePlan.data` runs on one device whatever the plan's backend,
+    so a simulator session's degraded read records its stages (the
+    gather included) under backend "local"."""
+    system = CodedSystem(_spec("rs", 6, 3), backend="simulator")
+    cw = system.codeword(RNG.integers(0, 1 << 16, (6, W_EDGE)))
+    system.fail([2])
+    system.read(cw)
+    before = metrics.REGISTRY.snapshot()
+    assert np.array_equal(system.read(cw), cw[:6])
+    after = metrics.REGISTRY.snapshot()
+    system.close()
+    assert _edge_delta(before, after, "edge_stage_seconds", "read",
+                       "local") == {s: 1 for s in EDGE_CASES["read"][0]}
+    assert _edge_delta(before, after, "edge_stage_seconds", "read",
+                       "simulator") == {}
+
+
+def test_edge_stages_reach_the_profiler_without_a_tracer(tmp_path):
+    """A `jax.profiler` trace on the CPU holds the `edge.*` annotations on
+    a host plane; no repro tracer is installed."""
+    import jax
+    from jax.profiler import ProfileData
+
+    system, call, payload = _edge_system("read")
+    assert trace.get_tracer() is None
+    with jax.profiler.trace(str(tmp_path)):
+        call(payload)
+    system.close()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    names = [ev.name for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             for ev in line.events if ev.name.startswith("edge.")]
+    assert names == [f"edge.{s}" for s in EDGE_CASES["read"][0]]
+
+
+def test_stream_pipeline_stages_go_through_the_edge_helper():
+    """`run_stream`'s h2d / dispatch / materialize are edge stages: one
+    each per chunk, with the chunks' bytes."""
+    plan = Encoder.plan(_spec("rs", 4, 4), backend="local")
+    x = RNG.integers(0, 1 << 16, (4, 3 * W_EDGE))
+    before = metrics.REGISTRY.snapshot()
+    with trace.installed() as t:
+        out = np.concatenate(list(plan.run_stream(x, chunk_w=W_EDGE)),
+                             axis=1)
+    after = metrics.REGISTRY.snapshot()
+    assert np.array_equal(out, plan.run(x))
+    assert _edge_delta(before, after, "edge_stage_seconds", "encode",
+                       "local") == {"h2d": 3, "dispatch": 3,
+                                    "materialize": 3}
+    assert _edge_delta(before, after, "edge_bytes_total", "encode",
+                       "local") == {"h2d": 3 * 4 * W_EDGE * 4,
+                                    "d2h": 3 * 4 * W_EDGE * 4}
+    assert [e["name"] for e in t.events(cat="edge")] == [
+        "edge.h2d", "edge.dispatch", "edge.h2d", "edge.materialize",
+        "edge.dispatch", "edge.h2d", "edge.materialize", "edge.dispatch",
+        "edge.materialize"]
+
+
+# ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
 
@@ -141,6 +272,55 @@ def test_registry_counter_gauge_histogram_roundtrip():
     assert "repro_lat_us_count" in text
     with pytest.raises(ValueError):
         reg.gauge("ops_total")  # name already registered as a counter
+
+
+def test_bound_handles_update_their_labelset():
+    reg = metrics.MetricsRegistry()
+    c, h = reg.counter("bytes_total"), reg.histogram("lat_s")
+    bc = c.labels(direction="h2d", op="encode")
+    bh = h.labels(op="encode", stage="prep")
+    bc.inc(5)
+    c.inc(2, op="encode", direction="h2d")      # same labelset, any order
+    for v in (3.0, 1.0, 2.0):
+        bh.observe(v)
+    h.observe(4.0, stage="prep", op="encode")
+    snap = reg.snapshot()
+    assert snap["bytes_total"]["values"] == {"direction=h2d,op=encode": 7}
+    assert snap["lat_s"]["values"]["op=encode,stage=prep"] == {
+        "count": 4, "sum": 10.0, "min": 1.0, "max": 4.0, "mean": 2.5}
+    reg.reset()                                  # a handle outlives reset
+    bc.inc(1)
+    assert reg.snapshot()["bytes_total"]["values"] == {
+        "direction=h2d,op=encode": 1}
+
+
+def test_bound_handles_lose_no_update_under_threads():
+    import sys
+
+    reg = metrics.MetricsRegistry()
+    bc = reg.counter("n_total").labels(t="x")
+    bh = reg.histogram("v").labels(t="x")
+
+    def writer():
+        for _ in range(2000):
+            bc.inc(1)
+            bh.observe(1.0)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=writer) for _ in range(8)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+        assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(old)
+    snap = reg.snapshot()
+    assert snap["n_total"]["values"]["t=x"] == 16000
+    assert snap["v"]["values"]["t=x"]["count"] == 16000
+    assert snap["v"]["values"]["t=x"]["sum"] == 16000.0
 
 
 def test_registry_snapshot_consistent_under_concurrency():
