@@ -23,7 +23,7 @@ import numpy as np
 from ..core import schedule
 from ..core.field import FERMAT_Q
 from ..core.simulator import RoundNetwork
-from ..obs.trace import stage
+from ..obs.trace import count_reduce, stage
 from .registry import Backend, BackendCapabilityError, register_backend
 
 
@@ -85,15 +85,44 @@ def run_local(plan, x: np.ndarray) -> np.ndarray:
 
     edge = {"op": "encode", "backend": "local"}
     with stage("prep", **edge):
-        xq = np.asarray(x) % plan.field.q
-        x32 = xq.astype(np.uint32)
+        x32 = to_field_u32(x, plan.field.q, edge)
     with stage("h2d", **edge) as s:
         xd = jnp.asarray(x32)
         s.moved(xd.nbytes)
-        # both host temporaries live until the upload returns and no
-        # longer, as in one `jnp.asarray(x % q, uint32)` (see `_finish`)
-        del x32, xq
+        del x32  # released once the upload returns (see `_finish`)
     return _finish(local_encode_callable(plan), xd, edge)
+
+
+def to_field_u32(x, q: int, edge: dict) -> np.ndarray:
+    """A host payload in the device format: a C-contiguous uint32 array
+    equal bit for bit to `(np.asarray(x) % q).astype(np.uint32)`, for
+    every input.  Runs inside the caller's `prep` stage.
+
+    A payload a storage user writes is canonical already, in [0, q):
+    16-bit data, and parity or survivors out of the field.  There `% q`,
+    a division per element on int64, returns its input, so one exact
+    check of the range and a cast replace it.  An unsigned dtype whose
+    every value lies below q is its own proof; int64 and uint64 take one
+    pass, the max of the bits read as uint64 (in the array's byte order),
+    where a negative value wraps far above q; other integers take min and
+    max.  An empty array,
+    a failed check and any other dtype take the `% q` pass, whose int64
+    temporary is freed on return, inside the caller's stage.  Each call
+    adds one to `edge_reduce_total{path, op, backend}`, `path` being
+    `canonical` or `reduced`, at the caller's `edge` labels."""
+    x = np.asarray(x)
+    canonical = False
+    if x.dtype.kind in "iu":
+        info = np.iinfo(x.dtype)
+        if info.min >= 0 and info.max < q:
+            canonical = True
+        elif x.size and x.dtype.itemsize == 8:
+            u64 = np.dtype(np.uint64).newbyteorder(x.dtype.byteorder)
+            canonical = bool(x.view(u64).max() < q)
+        elif x.size:
+            canonical = bool(x.min() >= 0 and x.max() < q)
+    count_reduce("canonical" if canonical else "reduced", **edge)
+    return (x if canonical else x % q).astype(np.uint32, order="C")
 
 
 def _finish(fn, xd, edge: dict, rows: int | None = None) -> np.ndarray:
@@ -242,7 +271,7 @@ def run_mesh(plan, x: np.ndarray) -> np.ndarray:
     fn = plan.mesh_callable()
     edge = {"op": "encode", "backend": "mesh"}
     with stage("prep", **edge):
-        x32 = (np.asarray(x) % plan.field.q).astype(np.uint32)
+        x32 = to_field_u32(x, plan.field.q, edge)
     with stage("h2d", **edge) as s:
         xd = jax.device_put(x32, mesh_sharding(plan))
         s.moved(xd.nbytes)

@@ -315,17 +315,16 @@ class EncodePlan(PlanStats):
 
     def _stream_device_fn(self):
         import jax
-        import numpy as _np
 
-        from .backends import mesh_sharding
+        from .backends import mesh_sharding, to_field_u32
 
         q = self.field.q
         spec = self.spec
         sharding = mesh_sharding(self) if self.backend == "mesh" else None
+        edge = {"op": self.op, "backend": self.backend}
 
         def to_device(c):
-            return jax.device_put(
-                _np.ascontiguousarray(c % q).astype(_np.uint32), sharding)
+            return jax.device_put(to_field_u32(c, q, edge), sharding)
 
         if self.backend == "mesh":
             fn = self.mesh_callable()

@@ -11,8 +11,8 @@ asserting them in tests:
               queue / service layers emit per-op spans tagged
               tenant/tag/group.  `trace.stage` times the API's host edge
               (gather, prep, h2d, dispatch, d2h, widen) always:
-              profiler annotations plus `edge_stage_seconds` and
-              `edge_bytes_total` in the registry.
+              profiler annotations plus `edge_stage_seconds`,
+              `edge_bytes_total` and `edge_reduce_total` in the registry.
     metrics — ONE labeled counter/gauge/histogram registry the layer
               stats classes (`RunStats`, `PlanStats`, `StreamStats`,
               `QueueStats`, `ServiceStats`) publish into, snapshottable

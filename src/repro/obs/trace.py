@@ -30,7 +30,8 @@ The API's host edge is timed by `stage`, always on and independent of any
 installed tracer: each stage is a `jax.profiler.TraceAnnotation`
 (`edge.<stage>`, on the profiler's clock, which the device ops share), an
 observation of `edge_stage_seconds` in `obs.metrics.REGISTRY`, and, where
-a tracer is installed, a complete event on it.
+a tracer is installed, a complete event on it.  `count_reduce` counts
+which way each payload reached the device format (`edge_reduce_total`).
 """
 from __future__ import annotations
 
@@ -39,7 +40,7 @@ import threading
 from contextlib import contextmanager
 from time import perf_counter_ns
 
-from .metrics import REGISTRY
+from .metrics import REGISTRY, BoundCounter
 
 
 class Tracer:
@@ -238,6 +239,10 @@ EDGE_SECONDS = REGISTRY.histogram(
 EDGE_BYTES = REGISTRY.counter(
     "edge_bytes_total", "bytes of the device arrays placed (h2d) or read "
     "back (d2h) at the host edge, by direction, op and backend")
+EDGE_REDUCE = REGISTRY.counter(
+    "edge_reduce_total", "host payloads converted to the device format, by "
+    "path (canonical: a range check and a cast; reduced: the % q pass), "
+    "op and backend")
 
 # the stages whose bytes cross the host-device link, and which way
 _DIRECTION = {"h2d": "h2d", "d2h": "d2h", "materialize": "d2h"}
@@ -263,6 +268,17 @@ class _Site:
 
 
 _SITES: dict[tuple[str, str, str], _Site] = {}
+_REDUCE: dict[tuple[str, str, str], BoundCounter] = {}
+
+
+def count_reduce(path: str, *, op: str, backend: str) -> None:
+    """Add one to `edge_reduce_total{path, op, backend}`, its labels
+    resolved once per (path, op, backend) as a stage's are."""
+    bound = _REDUCE.get((path, op, backend))
+    if bound is None:
+        bound = _REDUCE.setdefault((path, op, backend), EDGE_REDUCE.labels(
+            path=path, op=op, backend=backend))
+    bound.inc()
 
 
 class stage:

@@ -60,16 +60,15 @@ def run_local(plan, v: np.ndarray) -> np.ndarray:
     each host-edge stage is an `obs.trace.stage`."""
     import jax.numpy as jnp
 
-    from ..api.backends import _finish
+    from ..api.backends import _finish, to_field_u32
 
     edge = {"op": "decode", "backend": "local"}
     with stage("prep", **edge):
-        vq = np.asarray(v) % plan.field.q
-        v32 = vq.astype(np.uint32)
+        v32 = to_field_u32(v, plan.field.q, edge)
     with stage("h2d", **edge) as s:
         vd = jnp.asarray(v32)
         s.moved(vd.nbytes)
-        del v32, vq  # as in `api.backends.run_local`
+        del v32  # as in `api.backends.run_local`
     return _finish(local_decode_callable(plan), vd, edge)
 
 
@@ -127,11 +126,11 @@ def _mesh_callables(plan) -> list:
 def run_mesh(plan, v: np.ndarray) -> np.ndarray:
     import jax
 
-    from ..api.backends import _finish
+    from ..api.backends import _finish, to_field_u32
 
     edge = {"op": "decode", "backend": "mesh"}
     with stage("prep", **edge):
-        v32 = (np.asarray(v) % plan.field.q).astype(np.uint32)
+        v32 = to_field_u32(v, plan.field.q, edge)
     with stage("h2d", **edge) as s:
         vg = jax.device_put(v32, mesh_sharding(plan))
         s.moved(vg.nbytes)

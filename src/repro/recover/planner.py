@@ -272,17 +272,17 @@ class DecodePlan(PlanStats):
 
     def _stream_device_fn(self):
         import jax
-        import numpy as _np
 
+        from ..api.backends import to_field_u32
         from .backends import (_mesh_callables, local_decode_callable,
                                mesh_sharding)
 
         q = self.field.q
         sharding = mesh_sharding(self) if self.backend == "mesh" else None
+        edge = {"op": self.op, "backend": self.backend}
 
         def to_device(c):
-            return jax.device_put(
-                _np.ascontiguousarray(c % q).astype(_np.uint32), sharding)
+            return jax.device_put(to_field_u32(c, q, edge), sharding)
 
         if self.backend == "mesh":
             fns = _mesh_callables(self)
@@ -309,19 +309,18 @@ class DecodePlan(PlanStats):
         if f.q == FERMAT_Q:
             import jax.numpy as jnp
 
-            from ..api.backends import _finish
+            from ..api.backends import _finish, to_field_u32
             from ..kernels.ops import decode_blocks
 
             # one device whatever the plan's backend: label what runs
             edge = {"op": "read", "backend": "local"}
             with stage("prep", **edge):
-                vq = v % f.q
-                v32 = vq.astype(np.uint32)
+                v32 = to_field_u32(v, f.q, edge)
                 d32 = (self.tables.Dd % f.q).astype(np.uint32)
             with stage("h2d", **edge) as s:
                 vd, dd = jnp.asarray(v32), jnp.asarray(d32)
                 s.moved(vd.nbytes + dd.nbytes)
-                del v32, vq  # as in `api.backends.run_local`
+                del v32  # as in `api.backends.run_local`
             x = _finish(lambda vd: decode_blocks(vd, dd), vd, edge)
         else:
             x = f.matmul(self.tables.Dd.T, v)

@@ -251,6 +251,107 @@ def test_stream_pipeline_stages_go_through_the_edge_helper():
 
 
 # ---------------------------------------------------------------------------
+# host-edge conversion to the device format (`api.backends.to_field_u32`)
+# ---------------------------------------------------------------------------
+
+Q = FERMAT.q
+I64 = np.iinfo(np.int64)
+# q - 1 is 2**16, the largest parity symbol and one above 16-bit data
+REDUCE_VALUES = (0, Q - 1, Q, 1 << 32, (1 << 32) + 1, -1, -Q,
+                 int(I64.min), int(I64.max), 1 << 56)
+# ">i8": big-endian int64, whose bytes of 2**56 read natively are 1
+REDUCE_DTYPES = ("uint8", "uint16", "int32", "int64", "uint64", "float64",
+                 ">i8")
+
+
+def _fits(dtype: str, v: int) -> bool:
+    if np.dtype(dtype).kind == "f":
+        return True
+    info = np.iinfo(dtype)
+    return info.min <= v <= info.max
+
+
+def _reduce_oracle(x):
+    """`(np.asarray(x) % q).astype(np.uint32)`; the 8- and 16-bit dtypes
+    are widened first, since numpy refuses `% q` where q does not fit."""
+    if x.dtype.kind in "iu" and x.dtype.itemsize < 4:
+        x = x.astype(np.int64)
+    return (x % Q).astype(np.uint32)
+
+
+def _reduce_payload(dtype: str, value, layout: str):
+    """A small payload of `dtype` holding canonical symbols and `value`
+    (None: canonical symbols only), laid out as `layout`."""
+    hi = min(Q, np.iinfo(dtype).max + 1) if np.dtype(dtype).kind in "iu" \
+        else Q
+    x = RNG.integers(0, hi, (6, 8)).astype(dtype)
+    if value is not None:
+        x[3, 5] = value
+    if layout == "empty":
+        return x[:0]
+    return x[1::2] if layout == "row_slice" else x
+
+
+REDUCE_CASES = [(dt, v, layout) for dt in REDUCE_DTYPES
+                for v in (None,) + REDUCE_VALUES if v is None or _fits(dt, v)
+                for layout in ("contiguous", "row_slice")] + [
+    (dt, None, "empty") for dt in REDUCE_DTYPES]
+
+
+@pytest.mark.parametrize("dtype,value,layout", REDUCE_CASES)
+def test_to_field_u32_matches_the_reduction(dtype, value, layout):
+    """Bit for bit `% q` and a cast, C-contiguous uint32, for every dtype,
+    value and layout; `edge_reduce_total` counts the call once, under
+    `canonical` exactly where the dtype or one exact check proves every
+    symbol in [0, q)."""
+    from repro.api.backends import to_field_u32
+
+    x = _reduce_payload(dtype, value, layout)
+    edge = {"op": "convert", "backend": "test"}
+    before = metrics.REGISTRY.snapshot()
+    got = to_field_u32(x, Q, edge)
+    after = metrics.REGISTRY.snapshot()
+    assert got.dtype == np.uint32 and got.flags.c_contiguous
+    assert got.shape == x.shape
+    assert np.array_equal(got, _reduce_oracle(x))
+    proven = np.dtype(dtype).kind == "u" and np.dtype(dtype).itemsize <= 2
+    in_range = np.dtype(dtype).kind in "iu" and (
+        proven or (x.size > 0 and x.min() >= 0 and x.max() < Q))
+    path = "canonical" if in_range else "reduced"
+    assert _edge_delta(before, after, "edge_reduce_total", "convert",
+                       "test") == {path: 1}
+
+
+SHIFTS = (0, 1, -1, -3, 1 << 40)
+
+
+@pytest.mark.parametrize("op", ("encode", "read"))
+@pytest.mark.parametrize("shift", SHIFTS)
+def test_shifted_payload_same_bits_as_canonical(op, shift):
+    """A payload shifted by `shift` * q, negative ones included, encodes
+    and degraded-reads to the same bits as the canonical payload on the
+    local backend, and both match the simulator oracle; only the shifted
+    payload takes the `% q` pass."""
+    spec = _spec("rs", 6, 3)
+    x = RNG.integers(0, 1 << 16, (6, W_EDGE))
+    cw = _codeword(spec, x)
+    system = CodedSystem(spec, backend="local")
+    if op == "read":
+        system.fail([2])
+    call, payload, want = ((system.encode, x, cw[6:]) if op == "encode"
+                           else (system.read, cw, x))
+    canonical = call(payload)
+    before = metrics.REGISTRY.snapshot()
+    shifted = call(payload + shift * Q)
+    after = metrics.REGISTRY.snapshot()
+    system.close()
+    assert np.array_equal(canonical, want)
+    assert np.array_equal(shifted, want)
+    assert _edge_delta(before, after, "edge_reduce_total", op, "local") == {
+        "reduced" if shift else "canonical": 1}
+
+
+# ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
 
