@@ -23,7 +23,7 @@ import numpy as np
 from ..core import schedule
 from ..core.field import FERMAT_Q
 from ..core.simulator import RoundNetwork
-from ..obs.trace import count_reduce, stage
+from ..obs.trace import count_kernel, count_reduce, stage
 from .registry import Backend, BackendCapabilityError, register_backend
 
 
@@ -90,7 +90,24 @@ def run_local(plan, x: np.ndarray) -> np.ndarray:
         xd = jnp.asarray(x32)
         s.moved(xd.nbytes)
         del x32  # released once the upload returns (see `_finish`)
-    return _finish(local_encode_callable(plan), xd, edge)
+    return _finish(counted_kernel(local_encode_callable(plan),
+                                  local_kernel(plan), edge), xd, edge)
+
+
+def local_kernel(plan) -> str:
+    """The `local_kernel_total` label of an encode plan's local kernel:
+    `ntt` (the NTT fast path) or `matmul` (`encode_blocks`)."""
+    return "ntt" if plan.local_impl == "ntt" else "matmul"
+
+
+def counted_kernel(fn, kernel: str, edge: dict):
+    """`fn`, each call of which adds one to `local_kernel_total{kernel,
+    op, backend}` at `edge`'s labels: one per local device call (encode,
+    decode, read and each stream chunk)."""
+    def call(x):
+        count_kernel(kernel, **edge)
+        return fn(x)
+    return call
 
 
 def to_field_u32(x, q: int, edge: dict) -> np.ndarray:
@@ -286,6 +303,15 @@ def run_mesh(plan, x: np.ndarray) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def _refuse_shortened(backend: str, spec, missing: str) -> None:
+    """Refuse a shortened structured spec on a schedule backend."""
+    if spec.shortened():
+        raise BackendCapabilityError(
+            f"kind={spec.kind!r} K={spec.K}, R={spec.R} is a shortened "
+            f"structured code (neither of K, R divides the other): backend "
+            f"{backend!r} needs {missing}; backend='local' runs it")
+
+
 @register_backend("simulator")
 class SimulatorBackend(Backend):
     """Exact lockstep oracle on the paper's p-port round network.  Runs any
@@ -293,6 +319,11 @@ class SimulatorBackend(Backend):
     recorded thread-locally on `plan.last_stats`/`plan.sim_net`)."""
 
     measures_network = True
+
+    def validate(self, spec, op: str = "encode") -> None:
+        _refuse_shortened(self.name, spec, "the schedule that drops the "
+                          "zero sources' sends, not built yet")
+        super().validate(spec, op)
 
     def encode(self, plan, x):
         y, net = run_simulator(plan, x)
@@ -344,6 +375,9 @@ class MeshBackend(Backend):
 
     def validate(self, spec, op: str = "encode") -> None:
         # structural mismatch first: it holds on any device count
+        _refuse_shortened(self.name, spec, "the schedule that drops the zero "
+                          "sources' sends and several processors per device, "
+                          "neither built yet")
         if op == "encode" and spec.kind != "dft" and spec.K % spec.R != 0:
             raise BackendCapabilityError(
                 f"mesh encode covers the R | K framework grid (Sec. III-A); "

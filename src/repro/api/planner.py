@@ -165,7 +165,7 @@ def method_costs(spec: CodeSpec, sgrs: StructuredGRS | None) -> dict[str, Linear
             spec.K, spec.R, spec.p,
             cost_model.universal(min(spec.K, spec.R), spec.p), spec.W)
     }
-    if sgrs is not None:
+    if sgrs is not None and sgrs.parent is None:  # a shortened code has no rs schedule
         a2a = LinearCost(*cost_cauchy(sgrs, 0, spec.p))
         out["rs"] = cost_model.framework(spec.K, spec.R, spec.p, a2a, spec.W)
     return out
@@ -332,9 +332,11 @@ class EncodePlan(PlanStats):
                 return to_device, fn, lambda y: np.asarray(y, np.int64)
             return to_device, fn, lambda y: np.asarray(
                 y, np.int64)[: spec.R]
-        from .backends import local_encode_callable
+        from .backends import (counted_kernel, local_encode_callable,
+                               local_kernel)
 
-        fn = local_encode_callable(self)
+        fn = counted_kernel(local_encode_callable(self), local_kernel(self),
+                            edge)
         return to_device, fn, lambda y: np.asarray(y, np.int64)
 
     def schedule_ir(self):

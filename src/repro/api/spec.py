@@ -85,6 +85,15 @@ class CodeSpec:
         (enabling the RS/Lagrange-specific all-to-all schedules)."""
         return self.kind in ("rs", "lagrange")
 
+    def shortened(self) -> bool:
+        """Whether the structured code is a shortened one: neither of K
+        and R divides the other, so it is cut from a larger structured code
+        whose extra sources are zero (`core.cauchy.StructuredGRS`)."""
+        from ..core.cauchy import StructuredGRS
+
+        return self.structured() and StructuredGRS.parent_sources(
+            self.K, self.R) != self.K
+
     def default_matrix(self, field: Field | None = None) -> np.ndarray:
         """The (K, R) generator block implied by the spec alone (no explicit
         A): structured GRS / Lagrange A, permuted-DFT matrix, or the

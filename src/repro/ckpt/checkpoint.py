@@ -112,7 +112,6 @@ class CodedCheckpointer:
 
     def __post_init__(self):
         self.field = self.field or FERMAT
-        assert self.n_shards % self.n_parity == 0, "R | N (Remark 4)"
         # one CodedSystem session owns both coding directions: the encode
         # plan carries the StructuredGRS code and its generator block, and
         # degraded restores replan the decode side per erasure pattern.

@@ -17,6 +17,13 @@ This requires the alpha points of every block and the beta points to be
 each block's alpha grid and the beta grid in disjoint generator cosets so all
 K + R evaluation points stay distinct.
 
+Where neither of K and R divides the other (Remark 4's blocks do not tile),
+the code is *shortened*: built as the nearest structured (K', R) code and
+restricted to its first K alphas (HDFS RS-10-4 is 12+4 with two zero
+sources).  Its own generator is the plain u = v = 1 GRS over those points,
+so it has no block factorization of its own; the local kernels run it
+through the parent's (see `StructuredGRS.shorten_scales`).
+
 Cost (Thm. 7): C1 = 2*ceil(log_{p+1} R); C2 = C2(V_alpha,m) + C2(V_beta).
 
 Lagrange matrices (Remark 9) are the u = v = 1 case and reuse this machinery.
@@ -40,11 +47,17 @@ class StructuredGRS:
     betas are one structured R-point set.
     Case K < R (R = M*K): alphas are one structured K-point set; beta block m
     (size K) is `beta_blocks[m]`.
+
+    A shortened code (neither of K, R divides the other) keeps the blocks
+    of `parent`, the structured (K', R) code it was cut from, where
+    K' = `parent_sources(K, R)`; its `grs` is the systematic u = v = 1 GRS
+    over the parent's first K alphas and all its betas.
     """
 
     grs: SystematicGRS
     alpha_blocks: tuple[StructuredPoints, ...]
     beta_blocks: tuple[StructuredPoints, ...]
+    parent: "StructuredGRS | None" = None
 
     @property
     def field(self) -> Field:
@@ -58,15 +71,56 @@ class StructuredGRS:
     def R(self) -> int:
         return self.grs.R
 
+    @property
+    def dropped(self) -> np.ndarray:
+        """The parent's alphas past the first K: the points a shortened
+        code fixes at zero (empty for an unshortened code)."""
+        if self.parent is None:
+            return np.zeros(0, np.int64)
+        return self.parent.grs.alphas[self.K:]
+
+    def shorten_scales(self) -> tuple[np.ndarray, np.ndarray]:
+        """(h(alpha_k) for the K alphas, 1/h(beta_r) for the R betas), with
+        h(x) the product of (x - z) over the `dropped` points z.
+
+        The Lagrange basis of the K alphas is the parent's times
+        h(x)/h(alpha_k), so A[k, r] = h(alpha_k) A'[k, r] / h(beta_r): the
+        shortened parity is the parent's encode of h(alpha) . x, padded
+        with zero sources, scaled by 1/h(beta)."""
+        f, z = self.field, self.dropped
+        ha = np.array([_prod(f, f.sub(a, z)) for a in self.grs.alphas],
+                      np.int64)
+        hb = np.array([_prod(f, f.sub(b, z)) for b in self.grs.betas],
+                      np.int64)
+        return ha, f.inv(hb)
+
+    @staticmethod
+    def parent_sources(K: int, R: int) -> int:
+        """K' of the structured code a (K, R) code is built from: K itself
+        where one of K, R divides the other, else the next multiple of R
+        (K > R) or the least divisor of R above K (K < R)."""
+        if max(K, R) % min(K, R) == 0:
+            return K
+        if K > R:
+            return -(-K // R) * R
+        return next(d for d in range(K + 1, R + 1) if R % d == 0)
+
     @staticmethod
     def build(field: Field, K: int, R: int, P: int = 2, lagrange: bool = False) -> "StructuredGRS":
         """Build a structured systematic GRS (or Lagrange, u=v=1) code.
 
-        Requires min | max of (K, R). Blocks get consecutive phi offsets so
-        every evaluation point g^(o+i) * zeta^{j'} is distinct.
-        """
+        Where min(K, R) divides max(K, R), blocks get consecutive phi
+        offsets so every evaluation point g^(o+i) * zeta^{j'} is distinct;
+        otherwise the code is the (K', R) one shortened to K sources."""
+        Kp = StructuredGRS.parent_sources(K, R)
+        if Kp != K:
+            parent = StructuredGRS.build(field, Kp, R, P=P, lagrange=lagrange)
+            grs = SystematicGRS(field, parent.grs.alphas[:K],
+                                parent.grs.betas, np.ones(K, np.int64),
+                                np.ones(R, np.int64))
+            return StructuredGRS(grs, parent.alpha_blocks,
+                                 parent.beta_blocks, parent)
         big, small = max(K, R), min(K, R)
-        assert big % small == 0, "assume K | R or R | K (Remark 4)"
         n_small_sets = big // small + 1  # M blocks of the big side + 1 small set
 
         # factor `small` = M_s * P^H against q-1
@@ -100,6 +154,11 @@ class StructuredGRS:
     def scaling_factors(self, m: int) -> tuple[np.ndarray, np.ndarray]:
         """(phi_m, psi_m) of eqs. (26)-(27) (case K>=R) or the K<R analogue
         from Thm. 8: A_m = (P V_alpha)^-1 V_{beta,m} Q_m."""
+        if self.parent is not None:
+            raise NotImplementedError(
+                f"the shortened (K={self.K}, R={self.R}) code has no block "
+                "factorization of its own: its schedule is the parent's "
+                f"(K'={self.parent.K}) with zero sources, not built yet")
         f, grs = self.field, self.grs
         if self.K >= self.R:
             R = self.R

@@ -24,12 +24,17 @@ radix-2 NTT kernel instead:
   blocks (case K < R).  Total: O(K log Z) field ops per payload column
   vs O(K * R) for the matmul.
 
+A shortened code (`StructuredGRS.parent`, e.g. 10+4 cut from 12+4) runs
+its parent's transforms: h(alpha_k) folds into phi^-1 and 1/h(beta_r) into
+psi (`StructuredGRS.shorten_scales`), and the parent's extra sources are
+zero rows appended on the device, so only the K data rows cross the link.
+
 Everything is exact integer arithmetic mod q, so the fast path is bitwise
 identical to `encode_blocks` with `A_direct()` — the planner can switch
 freely (`EncodePlan.local_impl`).  Applicability is structural
 (`NTTEncodeParams.build` returns None when it does not hold), which in
-practice means: min(K, R) is a power of two >= 2 dividing q - 1, P == 2,
-and q is the Fermat prime.
+practice means: min(K', R) of the (parent) code is a power of two >= 2
+dividing q - 1, P == 2, and q is the Fermat prime.
 """
 from __future__ import annotations
 
@@ -63,10 +68,11 @@ class NTTEncodeParams:
     """Host-side constants of the NTT fast path (cached on HostTables).
 
     kind="dft": the transform is one forward NTT; every other field unused.
-    kind="grs": Z is the block transform size (min(K, R)), M the block
-    count; phi_inv/psi/twist are (M, Z) per-block scale vectors and
-    `case_kge` selects the K >= R (sum over blocks) vs K < R (concatenate
-    over beta blocks) combination rule.
+    kind="grs": Z is the block transform size (min(K', R) of the parent
+    code), M the block count; phi_inv/psi/twist are (M, Z) per-block scale
+    vectors and `case_kge` selects the K' >= R (sum over blocks) vs K' < R
+    (concatenate over beta blocks) combination rule.  `K` is the rows a
+    call uploads; the transforms take `rows`, zero beyond K.
     """
 
     kind: str                       # "dft" | "grs"
@@ -79,6 +85,13 @@ class NTTEncodeParams:
     psi: np.ndarray | None = None       # (M, Z) int64
     twist: np.ndarray | None = None     # (M, Z) int64  e_m[i] = (c_b/c_m)^i
 
+    @property
+    def rows(self) -> int:
+        """Source rows the transforms take: M*Z (K' >= R), Z (K' < R)."""
+        if self.kind == "dft":
+            return self.K
+        return self.M * self.Z if self.case_kge else self.Z
+
     @staticmethod
     def build(spec, sgrs) -> "NTTEncodeParams | None":
         """Params for the spec's local fast path, or None if inapplicable."""
@@ -90,6 +103,7 @@ class NTTEncodeParams:
             return NTTEncodeParams("dft", spec.K, spec.R)
         if sgrs is None:
             return None
+        short, sgrs = sgrs, sgrs.parent or sgrs
         f = sgrs.field
         g = f.generator
         blocks = sgrs.alpha_blocks + sgrs.beta_blocks
@@ -120,13 +134,24 @@ class NTTEncodeParams:
                 c_b = pow(g, bb.phi[0], f.q)
                 twist[m] = _pow_vec(int(f.mul(c_b, f.inv(np.int64(c_alpha)))),
                                     Z, f.q)
-        return NTTEncodeParams("grs", K, R, Z, M, case_kge,
+        if short is not sgrs:
+            # y = parent(h(alpha) . x) / h(beta); rows past K are zero
+            ha, hb_inv = short.shorten_scales()
+            if case_kge:
+                k = np.arange(short.K)
+                phi_inv[k // Z, k % Z] = f.mul(phi_inv[k // Z, k % Z], ha)
+                psi = f.mul(psi, hb_inv[None, :])
+            else:
+                phi_inv[:, :short.K] = f.mul(phi_inv[:, :short.K], ha)
+                psi = f.mul(psi, hb_inv.reshape(M, Z))
+        return NTTEncodeParams("grs", short.K, R, Z, M, case_kge,
                                phi_inv, psi, twist)
 
 
 @jax.named_scope("ntt_encode")
 def ntt_encode(x: jnp.ndarray, params: NTTEncodeParams) -> jnp.ndarray:
-    """Encode payload x (K, W) uint32 -> sink values (R, W) uint32.
+    """Encode payload x (K, W) uint32 -> sink values (R, W) uint32; a
+    shortened code's zero rows are appended here, on the device.
 
     Bitwise-equal to `encode_blocks(x, A_direct())`; traceable under jit
     (all per-spec constants fold in as literals).
@@ -135,6 +160,10 @@ def ntt_encode(x: jnp.ndarray, params: NTTEncodeParams) -> jnp.ndarray:
     if params.kind == "dft":
         return ntt_auto(x)
     Z, M, W = params.Z, params.M, x.shape[1]
+    if params.rows > params.K:
+        with jax.named_scope("ntt_encode.shorten"):
+            x = jnp.concatenate(
+                [x, jnp.zeros((params.rows - params.K, W), jnp.uint32)])
     phi_inv = jnp.asarray(params.phi_inv.T, jnp.uint32)[:, :, None]  # (Z,M,1)
     psi = jnp.asarray(params.psi.T, jnp.uint32)[:, :, None]
     twist = jnp.asarray(params.twist.T, jnp.uint32)[:, :, None]

@@ -244,10 +244,6 @@ def _coded_selfcheck(params, n_shards: int, n_parity: int,
     from ..ckpt.checkpoint import tree_to_bytes
     from ..core.field import FERMAT, bytes_to_symbols
 
-    if n_shards % n_parity:
-        raise SystemExit(
-            f"--coded-parity must divide --coded-shards (Remark 4): "
-            f"got {n_shards} shards, {n_parity} parity")
     raw, _ = tree_to_bytes(params)
     sym = bytes_to_symbols(raw)
     L = -(-sym.size // n_shards)

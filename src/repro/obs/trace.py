@@ -31,7 +31,9 @@ installed tracer: each stage is a `jax.profiler.TraceAnnotation`
 (`edge.<stage>`, on the profiler's clock, which the device ops share), an
 observation of `edge_stage_seconds` in `obs.metrics.REGISTRY`, and, where
 a tracer is installed, a complete event on it.  `count_reduce` counts
-which way each payload reached the device format (`edge_reduce_total`).
+which way each payload reached the device format (`edge_reduce_total`),
+and `count_kernel` which kernel each local device call ran
+(`local_kernel_total`).
 """
 from __future__ import annotations
 
@@ -243,6 +245,9 @@ EDGE_REDUCE = REGISTRY.counter(
     "edge_reduce_total", "host payloads converted to the device format, by "
     "path (canonical: a range check and a cast; reduced: the % q pass), "
     "op and backend")
+LOCAL_KERNEL = REGISTRY.counter(
+    "local_kernel_total", "device calls of the local kernels, by kernel "
+    "(ntt: the NTT encode; matmul: the dense field matmul), op and backend")
 
 # the stages whose bytes cross the host-device link, and which way
 _DIRECTION = {"h2d": "h2d", "d2h": "d2h", "materialize": "d2h"}
@@ -269,16 +274,28 @@ class _Site:
 
 _SITES: dict[tuple[str, str, str], _Site] = {}
 _REDUCE: dict[tuple[str, str, str], BoundCounter] = {}
+_KERNEL: dict[tuple[str, str, str], BoundCounter] = {}
+
+
+def _count_one(counter, bound_of: dict, **labels: str) -> None:
+    """Add one to `counter` at `labels`, resolved once per label set (in
+    `bound_of`) as a stage's are."""
+    key = tuple(labels.values())
+    bound = bound_of.get(key)
+    if bound is None:
+        bound = bound_of.setdefault(key, counter.labels(**labels))
+    bound.inc()
 
 
 def count_reduce(path: str, *, op: str, backend: str) -> None:
-    """Add one to `edge_reduce_total{path, op, backend}`, its labels
-    resolved once per (path, op, backend) as a stage's are."""
-    bound = _REDUCE.get((path, op, backend))
-    if bound is None:
-        bound = _REDUCE.setdefault((path, op, backend), EDGE_REDUCE.labels(
-            path=path, op=op, backend=backend))
-    bound.inc()
+    """Add one to `edge_reduce_total{path, op, backend}`."""
+    _count_one(EDGE_REDUCE, _REDUCE, path=path, op=op, backend=backend)
+
+
+def count_kernel(kernel: str, *, op: str, backend: str) -> None:
+    """Add one to `local_kernel_total{kernel, op, backend}`: one local
+    device call, `kernel` being `ntt` or `matmul`."""
+    _count_one(LOCAL_KERNEL, _KERNEL, kernel=kernel, op=op, backend=backend)
 
 
 class stage:

@@ -60,7 +60,7 @@ def run_local(plan, v: np.ndarray) -> np.ndarray:
     each host-edge stage is an `obs.trace.stage`."""
     import jax.numpy as jnp
 
-    from ..api.backends import _finish, to_field_u32
+    from ..api.backends import _finish, counted_kernel, to_field_u32
 
     edge = {"op": "decode", "backend": "local"}
     with stage("prep", **edge):
@@ -69,7 +69,8 @@ def run_local(plan, v: np.ndarray) -> np.ndarray:
         vd = jnp.asarray(v32)
         s.moved(vd.nbytes)
         del v32  # as in `api.backends.run_local`
-    return _finish(local_decode_callable(plan), vd, edge)
+    return _finish(counted_kernel(local_decode_callable(plan), "matmul",
+                                  edge), vd, edge)
 
 
 def mesh_sharding(plan):

@@ -273,7 +273,7 @@ class DecodePlan(PlanStats):
     def _stream_device_fn(self):
         import jax
 
-        from ..api.backends import to_field_u32
+        from ..api.backends import counted_kernel, to_field_u32
         from .backends import (_mesh_callables, local_decode_callable,
                                mesh_sharding)
 
@@ -297,7 +297,7 @@ class DecodePlan(PlanStats):
                      for y, (eb, _) in zip(ys, widths)], axis=0)
 
             return to_device, dev_fn, finalize
-        fn = local_decode_callable(self)
+        fn = counted_kernel(local_decode_callable(self), "matmul", edge)
         return to_device, fn, lambda y: np.asarray(y, np.int64)
 
     def data(self, v) -> np.ndarray:
@@ -309,7 +309,7 @@ class DecodePlan(PlanStats):
         if f.q == FERMAT_Q:
             import jax.numpy as jnp
 
-            from ..api.backends import _finish, to_field_u32
+            from ..api.backends import _finish, counted_kernel, to_field_u32
             from ..kernels.ops import decode_blocks
 
             # one device whatever the plan's backend: label what runs
@@ -321,7 +321,8 @@ class DecodePlan(PlanStats):
                 vd, dd = jnp.asarray(v32), jnp.asarray(d32)
                 s.moved(vd.nbytes + dd.nbytes)
                 del v32  # as in `api.backends.run_local`
-            x = _finish(lambda vd: decode_blocks(vd, dd), vd, edge)
+            x = _finish(counted_kernel(lambda vd: decode_blocks(vd, dd),
+                                       "matmul", edge), vd, edge)
         else:
             x = f.matmul(self.tables.Dd.T, v)
         return x[:, 0] if squeeze else x

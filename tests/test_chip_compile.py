@@ -79,6 +79,20 @@ def test_ntt_encode_rs_16_4_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_ntt_encode_rs_10_4_compiles(one_chip):
+    """HDFS RS-10-4 at its 1 MiB cells: the shortened code's NTT encode
+    (3 blocks of 4, two zero rows appended in the program) compiles to the
+    Pallas kernel and takes only the 10 uploaded rows."""
+    from repro.api import CodeSpec, Encoder
+    from repro.api.backends import local_encode_callable
+
+    plan = Encoder.plan(CodeSpec(kind="rs", K=10, R=4), backend="local")
+    assert plan.local_impl == "ntt"
+    fn = local_encode_callable(plan)
+    text = fn.lower(_u32((10, 1 << 19), one_chip)).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
 def test_mesh_encode_rs_4_4_compiles(topo, monkeypatch):
     """The rs 4+4 mesh encode on four described chips, one source per
     chip: the schedule's rounds lower to collective-permutes."""
