@@ -23,7 +23,8 @@ import numpy as np
 from ..core import schedule
 from ..core.field import FERMAT_Q
 from ..core.simulator import RoundNetwork
-from ..obs.trace import count_kernel, count_reduce, stage
+from ..obs.trace import (count_gather_fused, count_kernel, count_reduce,
+                         stage)
 from .registry import Backend, BackendCapabilityError, register_backend
 
 
@@ -110,36 +111,77 @@ def counted_kernel(fn, kernel: str, edge: dict):
     return call
 
 
-def to_field_u32(x, q: int, edge: dict) -> np.ndarray:
+# Symbols per tile of `to_field_u32`'s one pass: 32 Ki int64 are 256 KiB,
+# which stays in a core's L2 cache from the tile's range check to its
+# cast, so the caller's payload is read from DRAM once.
+_TILE = 1 << 15
+
+
+def to_field_u32(x, q: int, edge: dict, rows=None) -> np.ndarray:
     """A host payload in the device format: a C-contiguous uint32 array
-    equal bit for bit to `(np.asarray(x) % q).astype(np.uint32)`, for
-    every input.  Runs inside the caller's `prep` stage.
+    equal bit for bit to `(np.asarray(x)[rows] % q).astype(np.uint32)`
+    (all of `x` where `rows` is None), for every input.  Runs inside the
+    caller's `prep` stage.
 
     A payload a storage user writes is canonical already, in [0, q):
     16-bit data, and parity or survivors out of the field.  There `% q`,
     a division per element on int64, returns its input, so one exact
-    check of the range and a cast replace it.  An unsigned dtype whose
-    every value lies below q is its own proof; int64 and uint64 take one
-    pass, the max of the bits read as uint64 (in the array's byte order),
-    where a negative value wraps far above q; other integers take min and
-    max.  An empty array,
-    a failed check and any other dtype take the `% q` pass, whose int64
-    temporary is freed on return, inside the caller's stage.  Each call
-    adds one to `edge_reduce_total{path, op, backend}`, `path` being
-    `canonical` or `reduced`, at the caller's `edge` labels."""
+    check of the range and a cast replace it.  The pass walks each row
+    asked for in tiles of `_TILE` symbols, and checks and casts each
+    tile while it is in cache: the payload is read once, and the rows
+    `rows` of a wider array (the survivors in a codeword) are gathered
+    by the same pass, with no copy.  An unsigned dtype whose every value
+    lies below q is its own proof; int64 and uint64 take the max of the
+    bits read as uint64 (in the array's byte order), where a negative
+    value wraps far above q; other integers take min and max.  An empty
+    payload, a failed check and any other dtype take the `% q` pass
+    over the rows, whose int64 temporary is freed on return, inside the
+    caller's stage.  Each call adds one to `edge_reduce_total{path, op,
+    backend}`, `path` being `canonical` or `reduced`, and one to
+    `edge_gather_fused_total{op, backend}` where `rows` selects from a
+    wider array, at the caller's `edge` labels."""
     x = np.asarray(x)
-    canonical = False
-    if x.dtype.kind in "iu":
-        info = np.iinfo(x.dtype)
-        if info.min >= 0 and info.max < q:
-            canonical = True
-        elif x.size and x.dtype.itemsize == 8:
-            u64 = np.dtype(np.uint64).newbyteorder(x.dtype.byteorder)
-            canonical = bool(x.view(u64).max() < q)
-        elif x.size:
-            canonical = bool(x.min() >= 0 and x.max() < q)
+    picked = range(len(x)) if rows is None else rows
+    if rows is not None and len(rows) < len(x):
+        count_gather_fused(**edge)
+    out = np.empty((len(picked),) + x.shape[1:], np.uint32)
+    canonical = x.dtype.kind in "iu"
+    if canonical:
+        check = _range_check(x.dtype, q)
+        canonical = (check is None or out.size > 0) and _cast_in_tiles(
+            x, picked, out, check)
     count_reduce("canonical" if canonical else "reduced", **edge)
-    return (x if canonical else x % q).astype(np.uint32, order="C")
+    if canonical:
+        return out
+    del out
+    return ((x if rows is None else x[list(rows)]) % q).astype(
+        np.uint32, order="C")
+
+
+def _range_check(dtype: np.dtype, q: int):
+    """The exact test that a tile of integer `dtype` lies in [0, q), or
+    None where the dtype proves it."""
+    info = np.iinfo(dtype)
+    if info.min >= 0 and info.max < q:
+        return None
+    if dtype.itemsize == 8:
+        u64 = np.dtype(np.uint64).newbyteorder(dtype.byteorder)
+        return lambda t: t.view(u64).max() < q
+    return lambda t: t.min() >= 0 and t.max() < q
+
+
+def _cast_in_tiles(x, picked, out, check) -> bool:
+    """Cast rows `picked` of `x` into the rows of `out`, `_TILE` symbols
+    at a time, each tile checked (unless `check` is None) just before its
+    cast; False at the first tile that fails."""
+    for j, i in enumerate(picked):
+        src, dst = x[i].reshape(-1), out[j].reshape(-1)
+        for a in range(0, src.size, _TILE):
+            tile = src[a:a + _TILE]
+            if check is not None and not check(tile):
+                return False
+            np.copyto(dst[a:a + _TILE], tile, casting="unsafe")
+    return True
 
 
 def _finish(fn, xd, edge: dict, rows: int | None = None) -> np.ndarray:
@@ -344,6 +386,7 @@ class LocalBackend(Backend):
     matmul).  No communication schedule; uint32 Fermat arithmetic only."""
 
     supports_stream = True
+    takes_codeword = True
     field_note = f"the uint32 kernels are Fermat-only, q={FERMAT_Q}"
 
     def supports_field(self, q: int) -> bool:
@@ -365,6 +408,7 @@ class MeshBackend(Backend):
     R | K framework grid (Sec. III-A) for non-dft kinds."""
 
     supports_stream = True
+    takes_codeword = True
     field_note = f"the uint32 kernels are Fermat-only, q={FERMAT_Q}"
 
     def supports_field(self, q: int) -> bool:

@@ -86,6 +86,11 @@ class Backend:
                           per-chunk `encode`/`decode` calls.
       measures_network  — runs yield exact (C1, C2) network stats,
                           recorded thread-locally on `plan.last_stats`.
+      takes_codeword    — `decode` also takes the full (N, w) codeword
+                          and reads the survivors out of it itself
+                          (`plan.survivor_rows`; built-ins: local/mesh,
+                          whose conversion gathers them).  For the others
+                          the plan gathers them first.
       supports_field(q) — which moduli the executor handles (the uint32
                           jnp/Pallas kernels are Fermat-only).
       device_requirement(spec) — minimum jax device count to execute
@@ -95,6 +100,7 @@ class Backend:
     name: str = "?"
     supports_stream: bool = False
     measures_network: bool = False
+    takes_codeword: bool = False
     # optional one-line reason shown in the unsupported-field error
     # (set by backends whose supports_field is restrictive)
     field_note: str | None = None
@@ -133,7 +139,8 @@ class Backend:
 
     def decode(self, plan, v):
         """Execute a `DecodePlan`: (K, w) survivor symbols (ordered like
-        `plan.kept`) -> (|E|, w) repaired symbols, int64 mod q."""
+        `plan.kept`; where `takes_codeword`, also the full (N, w)
+        codeword) -> (|E|, w) repaired symbols, int64 mod q."""
         raise BackendCapabilityError(
             f"backend {self.name!r} does not implement decode")
 

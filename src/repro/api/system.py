@@ -45,7 +45,6 @@ from typing import Any, Iterable, Iterator
 
 import numpy as np
 
-from ..obs.trace import stage
 from .planner import ALPHA_DEFAULT, BETA_BITS_DEFAULT, EncodePlan, Encoder
 from .registry import get_backend
 from .spec import CodeSpec
@@ -287,32 +286,12 @@ class CodedSystem:
         return self._enc.run_batched(xs, chunk_w=chunk_w or self.chunk_w)
 
     # -- decode / degraded read ---------------------------------------------
-    def _survivor_view(self, v, plan, op: str,
-                       backend: str | None = None) -> np.ndarray:
-        """Normalize (N, ...) codeword rows or (K, ...) kept-ordered
-        survivor symbols to the (K, ...) form `plan` consumes.  The plan
-        is passed in (not re-resolved from the live erasure state) so one
-        operation slices and executes against ONE pattern even if a
-        concurrent `fail`/`heal` lands mid-flight.  Gathering the K rows
-        out of N is the `gather` stage of `op` on `backend` (the plan's
-        unless given; `obs.trace.stage`)."""
-        v = np.asarray(v)
-        if v.shape[0] == self.spec.N:
-            with stage("gather", op=op, backend=backend or plan.backend):
-                return v[list(plan.kept)]
-        if v.shape[0] == self.spec.K:
-            return v
-        raise ValueError(
-            f"expected the full (N={self.spec.N}, ...) codeword or the "
-            f"(K={self.spec.K}, ...) survivor symbols of system.kept, got "
-            f"leading dim {v.shape[0]}")
-
     def decode(self, v) -> np.ndarray:
         """Recompute the symbols at the failed positions from survivors:
         returns (|failed|,)/(|failed|, W) rows ordered like
         `system.failed` (empty while healthy)."""
-        plan = self.decode_plan  # pinned: one pattern for slice + run
-        return plan.run(self._survivor_view(v, plan, "decode"))
+        # the plan reads the survivors out of an (N, ...) codeword itself
+        return self.decode_plan.run(v)
 
     def read(self, v) -> np.ndarray:
         """Degraded read: the full original data (K,)/(K, W) from the
@@ -325,9 +304,9 @@ class CodedSystem:
                     f"expected (N={self.spec.N}, ...) or (K={self.spec.K},"
                     f" ...) rows, got leading dim {v.shape[0]}")
             return (v[: self.spec.K] % self.spec.q).astype(np.int64)
-        plan = self.decode_plan  # pinned: one pattern for slice + data
-        # `DecodePlan.data` runs on one device whatever the backend
-        return plan.data(self._survivor_view(v, plan, "read", "local"))
+        # `DecodePlan.data` runs on one device whatever the backend, and
+        # reads the survivors out of an (N, ...) codeword itself
+        return self.decode_plan.data(v)
 
     def decode_stream(self, payload, *, chunk_w: int | None = None
                       ) -> Iterator[np.ndarray]:
@@ -342,7 +321,7 @@ class CodedSystem:
 
         def _sliced():
             for piece in pieces:
-                yield self._survivor_view(piece, plan, "decode")
+                yield plan.survivors(piece, "decode", plan.backend)
 
         return plan.run_stream(_sliced(), chunk_w=chunk_w or self.chunk_w)
 
@@ -387,7 +366,7 @@ class CodedSystem:
         if v.shape[0] == N:
             healed = (v % q).astype(np.int64)
             if plan.erased:
-                healed[list(plan.erased)] = plan.run(v[list(plan.kept)])
+                healed[list(plan.erased)] = plan.run(v)
             return healed
         if v.shape[0] == K:
             comp = self._complement_plan(plan)

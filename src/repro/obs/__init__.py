@@ -12,7 +12,8 @@ asserting them in tests:
               tenant/tag/group.  `trace.stage` times the API's host edge
               (gather, prep, h2d, dispatch, d2h, widen) always:
               profiler annotations plus `edge_stage_seconds`,
-              `edge_bytes_total` and `edge_reduce_total` in the registry.
+              `edge_bytes_total`, `edge_reduce_total` and
+              `edge_gather_fused_total` in the registry.
     metrics — ONE labeled counter/gauge/histogram registry the layer
               stats classes (`RunStats`, `PlanStats`, `StreamStats`,
               `QueueStats`, `ServiceStats`) publish into, snapshottable

@@ -32,8 +32,9 @@ installed tracer: each stage is a `jax.profiler.TraceAnnotation`
 observation of `edge_stage_seconds` in `obs.metrics.REGISTRY`, and, where
 a tracer is installed, a complete event on it.  `count_reduce` counts
 which way each payload reached the device format (`edge_reduce_total`),
-and `count_kernel` which kernel each local device call ran
-(`local_kernel_total`).
+`count_gather_fused` each conversion that took its rows out of a wider
+array (`edge_gather_fused_total`), and `count_kernel` which kernel each
+local device call ran (`local_kernel_total`).
 """
 from __future__ import annotations
 
@@ -245,6 +246,10 @@ EDGE_REDUCE = REGISTRY.counter(
     "edge_reduce_total", "host payloads converted to the device format, by "
     "path (canonical: a range check and a cast; reduced: the % q pass), "
     "op and backend")
+EDGE_GATHER_FUSED = REGISTRY.counter(
+    "edge_gather_fused_total", "host payloads converted to the device "
+    "format straight from the rows of a wider array (the survivors in a "
+    "codeword), with no gather copy, by op and backend")
 LOCAL_KERNEL = REGISTRY.counter(
     "local_kernel_total", "device calls of the local kernels, by kernel "
     "(ntt: the NTT encode; matmul: the dense field matmul), op and backend")
@@ -274,6 +279,7 @@ class _Site:
 
 _SITES: dict[tuple[str, str, str], _Site] = {}
 _REDUCE: dict[tuple[str, str, str], BoundCounter] = {}
+_FUSED: dict[tuple[str, str], BoundCounter] = {}
 _KERNEL: dict[tuple[str, str, str], BoundCounter] = {}
 
 
@@ -290,6 +296,12 @@ def _count_one(counter, bound_of: dict, **labels: str) -> None:
 def count_reduce(path: str, *, op: str, backend: str) -> None:
     """Add one to `edge_reduce_total{path, op, backend}`."""
     _count_one(EDGE_REDUCE, _REDUCE, path=path, op=op, backend=backend)
+
+
+def count_gather_fused(*, op: str, backend: str) -> None:
+    """Add one to `edge_gather_fused_total{op, backend}`: one conversion
+    that gathered its rows out of a wider array in its single pass."""
+    _count_one(EDGE_GATHER_FUSED, _FUSED, op=op, backend=backend)
 
 
 def count_kernel(kernel: str, *, op: str, backend: str) -> None:

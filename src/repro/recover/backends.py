@@ -56,15 +56,16 @@ def local_decode_callable(plan):
 
 
 def run_local(plan, v: np.ndarray) -> np.ndarray:
-    """Single-device decode on the Pallas/jnp kernel path (no network);
-    each host-edge stage is an `obs.trace.stage`."""
+    """Single-device decode on the Pallas/jnp kernel path (no network) of
+    the survivors or the full codeword (`plan.survivor_rows`, gathered by
+    the conversion); each host-edge stage is an `obs.trace.stage`."""
     import jax.numpy as jnp
 
     from ..api.backends import _finish, counted_kernel, to_field_u32
 
     edge = {"op": "decode", "backend": "local"}
     with stage("prep", **edge):
-        v32 = to_field_u32(v, plan.field.q, edge)
+        v32 = to_field_u32(v, plan.field.q, edge, plan.survivor_rows(v))
     with stage("h2d", **edge) as s:
         vd = jnp.asarray(v32)
         s.moved(vd.nbytes)
@@ -131,7 +132,7 @@ def run_mesh(plan, v: np.ndarray) -> np.ndarray:
 
     edge = {"op": "decode", "backend": "mesh"}
     with stage("prep", **edge):
-        v32 = to_field_u32(v, plan.field.q, edge)
+        v32 = to_field_u32(v, plan.field.q, edge, plan.survivor_rows(v))
     with stage("h2d", **edge) as s:
         vg = jax.device_put(v32, mesh_sharding(plan))
         s.moved(vg.nbytes)

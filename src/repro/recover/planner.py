@@ -4,6 +4,7 @@
     plan = Decoder.plan(spec, erased=(2, 17), backend="simulator")
     lost = plan.run(v)        # v: (K, W) symbols at plan.kept -> (|E|, W)
     x    = plan.data(v)       # full original data (K, W)
+    lost = plan.run(cw)       # or the full (N, W) codeword: its kept rows
 
 The systematic codeword of a spec is [x | EncodePlan.run(x)] — data symbol
 k lives on processor k, parity symbol r on processor K + r.  `erased` is a
@@ -221,23 +222,50 @@ class DecodePlan(PlanStats):
         """(K, |E|) repair matrix: erased symbols are v^T D per column."""
         return self.tables.D
 
+    def survivor_rows(self, v) -> tuple[int, ...] | None:
+        """The rows of `v` that hold the survivor symbols `run` / `data`
+        consume: None where `v` is the (K, ...) survivors ordered like
+        `plan.kept`, `plan.kept` where it is the full (N, ...) codeword."""
+        n = np.shape(v)[0]
+        if n == self.spec.K:
+            return None
+        if n == self.spec.N:
+            return self.kept
+        raise ValueError(
+            f"expected the full (N={self.spec.N}, ...) codeword or the "
+            f"(K={self.spec.K}, ...) survivor symbols of plan.kept, got "
+            f"leading dim {n}")
+
+    def survivors(self, v, op: str, backend: str) -> np.ndarray:
+        """`v`'s (K, ...) survivor rows: `v` itself, or the rows
+        `plan.kept` gathered out of the codeword, as the `gather` stage
+        of `op` on `backend` (`obs.trace.stage`)."""
+        v = np.asarray(v)
+        rows = self.survivor_rows(v)
+        if rows is None:
+            return v
+        with stage("gather", op=op, backend=backend):
+            return v[list(rows)]
+
     def _check(self, v) -> tuple[np.ndarray, bool]:
         v = np.asarray(v)
-        if v.shape[0] != self.spec.K:
-            raise ValueError(
-                f"v must carry the K={self.spec.K} survivor symbols of "
-                f"plan.kept along its leading dim, got {v.shape}")
+        self.survivor_rows(v)
         return (v[:, None], True) if v.ndim == 1 else (v, False)
 
     def run(self, v) -> np.ndarray:
         """Recompute the erased symbols: v (K,)/(K, W) survivor symbols
-        ordered like `plan.kept` -> (|E|,)/(|E|, W) repaired symbols
-        ordered like `plan.erased`."""
+        ordered like `plan.kept`, or the full (N,)/(N, W) codeword ->
+        (|E|,)/(|E|, W) repaired symbols ordered like `plan.erased`.  A
+        backend that `takes_codeword` reads the survivors out of the
+        codeword itself; for any other the plan gathers them first."""
         v, squeeze = self._check(v)
         if not self.erased:
             y = np.zeros((0, v.shape[1]), np.int64)
         else:
-            y = get_backend(self.backend).decode(self, v)
+            be = get_backend(self.backend)
+            if not be.takes_codeword:
+                v = self.survivors(v, "decode", self.backend)
+            y = be.decode(self, v)
         return y[:, 0] if squeeze else y
 
     def run_stream(self, payload, *, chunk_w: int | None = None):
@@ -301,21 +329,23 @@ class DecodePlan(PlanStats):
         return to_device, fn, lambda y: np.asarray(y, np.int64)
 
     def data(self, v) -> np.ndarray:
-        """Decode the full original data x (K, W) from the survivors (the
-        degraded-read path).  Runs on the kernel solve path for the Fermat
-        field, the exact host matmul otherwise — bitwise identical."""
+        """Decode the full original data x (K, W) from the survivors, (K,
+        W) or read out of the full (N, W) codeword as `run` does (the
+        degraded-read path).  Runs on the kernel solve path for the
+        Fermat field, whose conversion gathers the survivors in its one
+        pass, the exact host matmul otherwise — bitwise identical."""
         v, squeeze = self._check(v)
         f = self.field
+        # one device whatever the plan's backend: label what runs
+        edge = {"op": "read", "backend": "local"}
         if f.q == FERMAT_Q:
             import jax.numpy as jnp
 
             from ..api.backends import _finish, counted_kernel, to_field_u32
             from ..kernels.ops import decode_blocks
 
-            # one device whatever the plan's backend: label what runs
-            edge = {"op": "read", "backend": "local"}
             with stage("prep", **edge):
-                v32 = to_field_u32(v, f.q, edge)
+                v32 = to_field_u32(v, f.q, edge, self.survivor_rows(v))
                 d32 = (self.tables.Dd % f.q).astype(np.uint32)
             with stage("h2d", **edge) as s:
                 vd, dd = jnp.asarray(v32), jnp.asarray(d32)
@@ -324,7 +354,7 @@ class DecodePlan(PlanStats):
             x = _finish(counted_kernel(lambda vd: decode_blocks(vd, dd),
                                        "matmul", edge), vd, edge)
         else:
-            x = f.matmul(self.tables.Dd.T, v)
+            x = f.matmul(self.tables.Dd.T, self.survivors(v, **edge))
         return x[:, 0] if squeeze else x
 
     def cost(self) -> LinearCost:

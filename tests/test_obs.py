@@ -141,7 +141,8 @@ def _edge_delta(before, after, family, op, backend):
         else:
             d = v - (old or 0)
         if d:
-            out[labels.popitem()[1]] = d
+            # None keys a family with no label beyond op and backend
+            out[labels.popitem()[1] if labels else None] = d
     return out
 
 
@@ -160,11 +161,12 @@ def _edge_system(op):
 
 
 # stages in order, and bytes h2d / d2h, of one call at K=6, R=3, |E|=1:
-# the read uploads the 6x6 data matrix (144 B) beside the survivors
+# the read uploads the 6x6 data matrix (144 B) beside the survivors; the
+# read and decode of a codeword gather its survivors in `prep`
 EDGE_CASES = {
     "encode": (EDGE, 6 * W_EDGE * 4, 3 * W_EDGE * 4),
-    "read": (("gather",) + EDGE, 6 * W_EDGE * 4 + 144, 6 * W_EDGE * 4),
-    "decode": (("gather",) + EDGE, 6 * W_EDGE * 4, 1 * W_EDGE * 4),
+    "read": (EDGE, 6 * W_EDGE * 4 + 144, 6 * W_EDGE * 4),
+    "decode": (EDGE, 6 * W_EDGE * 4, 1 * W_EDGE * 4),
 }
 
 
@@ -183,6 +185,9 @@ def test_edge_stages_counted_in_order(op):
                        "local") == {s: 2 for s in stages}
     assert _edge_delta(before, after, "edge_bytes_total", op,
                        "local") == {"h2d": 2 * h2d, "d2h": 2 * d2h}
+    # a codeword's survivors are gathered by the conversion, once a call
+    assert _edge_delta(before, after, "edge_gather_fused_total", op,
+                       "local") == ({} if op == "encode" else {None: 2})
     # the installed tracer received the same stages, in order
     evs = t.events(cat="edge")
     assert [e["name"] for e in evs] == [f"edge.{s}" for s in stages] * 2
@@ -194,7 +199,7 @@ def test_edge_stages_counted_in_order(op):
 def test_read_stages_labelled_with_the_backend_that_runs():
     """`DecodePlan.data` runs on one device whatever the plan's backend,
     so a simulator session's degraded read records its stages (the
-    gather included) under backend "local"."""
+    gather fused into `prep`) under backend "local"."""
     system = CodedSystem(_spec("rs", 6, 3), backend="simulator")
     cw = system.codeword(RNG.integers(0, 1 << 16, (6, W_EDGE)))
     system.fail([2])
@@ -207,6 +212,8 @@ def test_read_stages_labelled_with_the_backend_that_runs():
                        "local") == {s: 1 for s in EDGE_CASES["read"][0]}
     assert _edge_delta(before, after, "edge_stage_seconds", "read",
                        "simulator") == {}
+    assert _edge_delta(before, after, "edge_gather_fused_total", "read",
+                       "local") == {None: 1}
 
 
 def test_edge_stages_reach_the_profiler_without_a_tracer(tmp_path):
@@ -320,6 +327,127 @@ def test_to_field_u32_matches_the_reduction(dtype, value, layout):
     path = "canonical" if in_range else "reduced"
     assert _edge_delta(before, after, "edge_reduce_total", "convert",
                        "test") == {path: 1}
+
+
+def _convert_and_count(x, rows):
+    """`to_field_u32(x, Q, ..., rows)` checked against `% q` and a cast of
+    `x[rows]`: bit-equal, C-contiguous uint32; returns the call's counts
+    ({path: 1} of `edge_reduce_total`, `edge_gather_fused_total`)."""
+    from repro.api.backends import to_field_u32
+
+    edge = {"op": "convert", "backend": "test"}
+    before = metrics.REGISTRY.snapshot()
+    got = to_field_u32(x, Q, edge, rows)
+    after = metrics.REGISTRY.snapshot()
+    want = _reduce_oracle(x if rows is None else x[list(rows)])
+    assert got.dtype == np.uint32 and got.flags.c_contiguous
+    assert got.shape == want.shape and np.array_equal(got, want)
+    return (_edge_delta(before, after, "edge_reduce_total", "convert",
+                        "test"),
+            _edge_delta(before, after, "edge_gather_fused_total", "convert",
+                        "test").get(None, 0))
+
+
+def _tiled_payload(dtype):
+    """Four rows of canonical symbols, each a little over three tiles of
+    the conversion's pass."""
+    from repro.api.backends import _TILE
+
+    return RNG.integers(0, Q, (4, 3 * _TILE + 5)).astype(dtype)
+
+
+# where in a row one symbol leaves [0, q): in tiles of `_TILE` symbols
+TILE_SPOTS = {"first": lambda t: 3, "middle": lambda t: t + 7,
+              "last": lambda t: 3 * t + 2,
+              "before_a_boundary": lambda t: 2 * t - 1}
+
+
+@pytest.mark.parametrize("value", (-1, Q))
+@pytest.mark.parametrize("dtype", ("int64", "int32"))
+@pytest.mark.parametrize("spot", sorted(TILE_SPOTS))
+def test_to_field_u32_finds_an_off_range_symbol_in_any_tile(spot, dtype,
+                                                             value):
+    """A payload wider than three tiles with one symbol off [0, q) in the
+    first, a middle or the last tile, or just before a tile boundary,
+    takes the `% q` pass: bit-equal, counted once as `reduced`, whether
+    the pass reads every row or a selection holding that row; a selection
+    without that row is canonical."""
+    from repro.api.backends import _TILE
+
+    x = _tiled_payload(dtype)
+    x[2, TILE_SPOTS[spot](_TILE)] = value
+    assert _convert_and_count(x, None) == ({"reduced": 1}, 0)
+    assert _convert_and_count(x, (3, 2)) == ({"reduced": 1}, 1)
+    assert _convert_and_count(x, (0, 3, 1)) == ({"canonical": 1}, 1)
+
+
+ROW_LISTS = {"subset": lambda n: [0, n - 1],
+             "reordered_subset": lambda n: [n - 1, 0],
+             "full_set": lambda n: list(range(n))}
+
+
+@pytest.mark.parametrize("value", (None, -1))
+@pytest.mark.parametrize("layout", ("contiguous", "row_slice", "tiled"))
+@pytest.mark.parametrize("rows", sorted(ROW_LISTS))
+def test_to_field_u32_gathers_the_rows_asked_for(rows, layout, value):
+    """`rows` picks rows of the payload in the conversion's one pass:
+    bit-equal to `x[rows] % q` and a cast, counted once in
+    `edge_reduce_total`, and once in `edge_gather_fused_total` exactly
+    where `rows` selects from a wider array."""
+    if layout == "tiled":
+        x = _tiled_payload("int64")
+        if value is not None:
+            x[-1, -1] = value
+    else:
+        x = _reduce_payload("int64", value, layout)
+    picked = ROW_LISTS[rows](len(x))
+    off = value is not None and (layout == "row_slice" and 1 in picked
+                                 or layout == "contiguous" and 3 in picked
+                                 or layout == "tiled"
+                                 and len(x) - 1 in picked)
+    assert _convert_and_count(x, picked) == (
+        {"reduced" if off else "canonical": 1}, int(len(picked) < len(x)))
+
+
+# one R-loss pattern per stripe beside every single loss: data and parity
+FUSED_LOSSES = {(6, 3): (0, 4, 7), (10, 4): (1, 5, 10, 13)}
+FUSED_CASES = [(K, R, lost) for (K, R), many in FUSED_LOSSES.items()
+               for lost in [(e,) for e in range(K + R)] + [many]]
+
+
+@pytest.mark.parametrize("K,R,lost", FUSED_CASES)
+def test_local_read_and_decode_of_a_codeword_match_the_oracle(K, R, lost):
+    """On the local backend the degraded read and the decode of an (N, W)
+    codeword, canonical or shifted by a multiple of q, return the data and
+    the lost rows of the numpy field oracle's codeword (and, where the
+    code is not shortened, the simulator's decode); each call converts
+    the survivors straight out of the codeword, once."""
+    spec = _spec("rs", K, R)
+    x = RNG.integers(0, 1 << 16, (K, W_EDGE))
+    A = Encoder.plan(spec, backend="local").A
+    cw = np.concatenate([x, FERMAT.matmul(A.T, x)])
+    if K % R == 0:              # the schedule backends run no shortened code
+        oracle = CodedSystem(spec, backend="simulator").fail(lost)
+        assert np.array_equal(oracle.decode(cw), cw[list(lost)])
+    damaged = cw.copy()
+    damaged[list(lost)] = 0
+    system = CodedSystem(spec, backend="local").fail(lost)
+    for shift in (0, -2):
+        payload = damaged + shift * Q
+        before = metrics.REGISTRY.snapshot()
+        read, repaired = system.read(payload), system.decode(payload)
+        after = metrics.REGISTRY.snapshot()
+        assert np.array_equal(read, x), shift
+        assert np.array_equal(repaired, cw[list(lost)]), shift
+        for op in ("read", "decode"):
+            assert _edge_delta(before, after, "edge_gather_fused_total", op,
+                               "local") == {None: 1}
+            assert _edge_delta(before, after, "edge_reduce_total", op,
+                               "local") == {
+                "reduced" if shift else "canonical": 1}
+            assert "gather" not in _edge_delta(
+                before, after, "edge_stage_seconds", op, "local")
+    system.close()
 
 
 SHIFTS = (0, 1, -1, -3, 1 << 40)
